@@ -69,7 +69,7 @@ def _subjaxprs(eqn) -> Iterable[Any]:
 def jaxpr_peak(jaxpr) -> int:
     """Peak live bytes of one jaxpr, equations walked in program
     order; sub-computations (scan/cond/remat bodies) recurse."""
-    from jax import core as jcore
+    from jax.extend import core as jcore
 
     last_use = {}
     for i, eqn in enumerate(jaxpr.eqns):

@@ -58,7 +58,7 @@ def _aval_bytes(var) -> float:
 
 
 def _is_lit(v) -> bool:
-    return not hasattr(v, "count")      # jax.core.Literal has no .count
+    return not hasattr(v, "count")      # jax.extend.core.Literal has no .count
 
 
 class _TraceState:

@@ -21,11 +21,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-try:  # moved between jax versions
-    from jax.experimental.shard_map import shard_map
-except ImportError:  # pragma: no cover
-    from jax.shard_map import shard_map  # type: ignore
-
 
 def stage_split(tree: Any, n_stages: int) -> Any:
     """Reshape every stacked-layer leaf (L, ...) -> (S, L/S, ...)."""
@@ -74,6 +69,6 @@ def pipeline_apply(stage_fn: Callable[[Any, jax.Array], jax.Array],
         mask = (s == n_stages - 1).astype(ybuf.dtype)
         return jax.lax.psum(ybuf * mask, "stage")
 
-    return shard_map(inner, mesh=mesh,
-                     in_specs=(P("stage"), P()),
-                     out_specs=P(), check_rep=False)
+    return jax.shard_map(inner, mesh=mesh,
+                         in_specs=(P("stage"), P()),
+                         out_specs=P(), check_vma=False)

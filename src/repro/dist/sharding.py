@@ -256,15 +256,11 @@ def axis_rules(recipe: Optional[Recipe]):
 
 
 def _current_mesh():
-    try:
-        from jax._src import mesh as mesh_lib
-        env = mesh_lib.thread_resources.env
-        m = env.physical_mesh
-        if m is not None and not m.empty:
-            return m
-    except Exception:
-        pass
-    return None
+    """The ambient mesh ``jax.set_mesh`` installed (what
+    ``launch.mesh.use_mesh`` enters), or None outside one. Abstract:
+    readable inside ``jit`` traces, where ``constrain`` runs."""
+    m = jax.sharding.get_abstract_mesh()
+    return None if m.empty else m
 
 
 def constrain(x: jax.Array, logical_axes: Sequence[Optional[str]]):
@@ -277,8 +273,7 @@ def constrain(x: jax.Array, logical_axes: Sequence[Optional[str]]):
     if mesh is None:
         return x
     spec = sanitize_spec(recipe.spec_for(logical_axes), x.shape, mesh)
-    return jax.lax.with_sharding_constraint(
-        x, NamedSharding(mesh, spec))
+    return jax.lax.with_sharding_constraint(x, spec)
 
 
 # ---------------------------------------------------------------------------

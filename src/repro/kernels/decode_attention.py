@@ -9,6 +9,11 @@ cache length instead of the (single) query.
 
     grid = (B * Hkv, n_splits)
     per program: q group tile (G, D), kv tile (block_k, D)
+
+Partials come out as (B * Hkv, n_splits, G, ·) with one (G, ·) block
+per program: the TPU block tiling takes a block's last two dims only
+when they are (8, 128)-aligned or the array's own, so the split axis
+stays out of them.
 """
 from __future__ import annotations
 
@@ -36,9 +41,33 @@ def _decode_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, m_ref, l_ref, *,
     l = jnp.sum(p, axis=-1, keepdims=True)
     acc = jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())),
                               preferred_element_type=jnp.float32)
-    o_ref[0] = acc.astype(o_ref.dtype)                # (G, D)
-    m_ref[0] = m
-    l_ref[0] = l
+    o_ref[0, 0] = acc.astype(o_ref.dtype)             # (G, D)
+    m_ref[0, 0] = m
+    l_ref[0, 0] = l
+
+
+def merge_partials(o, m, l, axis: int = 1) -> jax.Array:
+    """Combine per-split online-softmax partials (acc, max, sum) along
+    the split ``axis`` -> normalized attention output (f32)."""
+    m_all = jnp.max(m, axis=axis, keepdims=True)
+    w = jnp.exp(m - m_all)
+    l_all = jnp.sum(l * w, axis=axis)
+    return jnp.sum(o * w, axis=axis) / jnp.maximum(l_all, 1e-30)
+
+
+def split_partials_specs(G: int, D: int):
+    """(out_specs, out_shape factory) of the contiguous split-KV kernels'
+    (acc, m, l) partials over grid (B * Hkv, n_splits)."""
+    specs = [pl.BlockSpec((1, 1, G, D), lambda bh, s: (bh, s, 0, 0)),
+             pl.BlockSpec((1, 1, G, 1), lambda bh, s: (bh, s, 0, 0)),
+             pl.BlockSpec((1, 1, G, 1), lambda bh, s: (bh, s, 0, 0))]
+
+    def shapes(BH: int, ns: int):
+        return [jax.ShapeDtypeStruct((BH, ns, G, D), jnp.float32),
+                jax.ShapeDtypeStruct((BH, ns, G, 1), jnp.float32),
+                jax.ShapeDtypeStruct((BH, ns, G, 1), jnp.float32)]
+
+    return specs, shapes
 
 
 def decode_attention_splitkv(q, k_cache, v_cache, kv_mask, *,
@@ -63,6 +92,7 @@ def decode_attention_splitkv(q, k_cache, v_cache, kv_mask, *,
         mk = jnp.pad(mk, ((0, 0), (0, 0), (0, Wp - W)))
 
     kern = functools.partial(_decode_kernel, sm_scale=1.0 / math.sqrt(D))
+    out_specs, out_shape = split_partials_specs(G, D)
     o, m, l = pl.pallas_call(
         kern,
         grid=(B * Hkv, ns),
@@ -72,25 +102,11 @@ def decode_attention_splitkv(q, k_cache, v_cache, kv_mask, *,
             pl.BlockSpec((1, block_k, D), lambda bh, s: (bh, s, 0)),
             pl.BlockSpec((1, 1, block_k), lambda bh, s: (bh, 0, s)),
         ],
-        out_specs=[
-            pl.BlockSpec((1, G, D), lambda bh, s: (bh, s, 0)),
-            pl.BlockSpec((1, G, 1), lambda bh, s: (bh, s, 0)),
-            pl.BlockSpec((1, G, 1), lambda bh, s: (bh, s, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B * Hkv, ns * G, D), jnp.float32),
-            jax.ShapeDtypeStruct((B * Hkv, ns * G, 1), jnp.float32),
-            jax.ShapeDtypeStruct((B * Hkv, ns * G, 1), jnp.float32),
-        ],
+        out_specs=out_specs,
+        out_shape=out_shape(B * Hkv, ns),
         interpret=interpret,
     )(qg, kt, vt, mk)
 
     # merge partials across splits (tiny, plain XLA)
-    o = o.reshape(B * Hkv, ns, G, D)
-    m = m.reshape(B * Hkv, ns, G, 1)
-    l = l.reshape(B * Hkv, ns, G, 1)
-    m_all = jnp.max(m, axis=1, keepdims=True)
-    w = jnp.exp(m - m_all)
-    l_all = jnp.sum(l * w, axis=1)
-    out = jnp.sum(o * w, axis=1) / jnp.maximum(l_all, 1e-30)
-    return out.reshape(B, Hkv, G, D).reshape(B, Hq, D).astype(q.dtype)
+    out = merge_partials(o, m, l)
+    return out.reshape(B, Hq, D).astype(q.dtype)

@@ -86,6 +86,9 @@ def sort_by_expert(x, expert_of_row, n_experts: int, block_m: int,
     slot_expert = jnp.sum(
         (jnp.arange(Tp)[:, None] >= pad_off[None, 1:]).astype(jnp.int32),
         axis=-1)                                       # slot -> expert
-    block_expert = slot_expert[::block_m]
+    # the spare blocks past the last group hold no rows; point them at a
+    # real expert, since the TPU bounds-checks the weight DMA (the
+    # interpreter silently clamps)
+    block_expert = jnp.minimum(slot_expert[::block_m], n_experts - 1)
     inv = jnp.zeros((T,), jnp.int32).at[order].set(dest)
     return x_pad, block_expert.astype(jnp.int32), inv, Tp

@@ -29,7 +29,14 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.decode_attention import (merge_partials,
+                                            split_partials_specs)
+from repro.kernels.paged_attention import (init_partials, paged_epilogue,
+                                           paged_grid_spec,
+                                           paged_partials_shape,
+                                           paged_prologue, page_rows_valid,
+                                           softmax_update)
 
 NEG_INF = -1e30
 
@@ -176,9 +183,9 @@ def _quant_decode_kernel(q_ref, k_ref, v_ref, ks_ref, vs_ref, mask_ref,
     l = jnp.sum(p, axis=-1, keepdims=True)
     acc = jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())),
                               preferred_element_type=jnp.float32)
-    o_ref[0] = acc.astype(o_ref.dtype)
-    m_ref[0] = m
-    l_ref[0] = l
+    o_ref[0, 0] = acc.astype(o_ref.dtype)
+    m_ref[0, 0] = m
+    l_ref[0, 0] = l
 
 
 def quant_decode_attention_splitkv(q, k_q, v_q, k_scale, v_scale, kv_mask,
@@ -209,6 +216,7 @@ def quant_decode_attention_splitkv(q, k_q, v_q, k_scale, v_scale, kv_mask,
 
     kern = functools.partial(_quant_decode_kernel,
                              sm_scale=1.0 / math.sqrt(D))
+    out_specs, out_shape = split_partials_specs(G, D)
     o, m, l = pl.pallas_call(
         kern,
         grid=(B * Hkv, ns),
@@ -220,63 +228,37 @@ def quant_decode_attention_splitkv(q, k_q, v_q, k_scale, v_scale, kv_mask,
             pl.BlockSpec((1, 1, block_k), lambda bh, s: (bh, 0, s)),
             pl.BlockSpec((1, 1, block_k), lambda bh, s: (bh, 0, s)),
         ],
-        out_specs=[
-            pl.BlockSpec((1, G, D), lambda bh, s: (bh, s, 0)),
-            pl.BlockSpec((1, G, 1), lambda bh, s: (bh, s, 0)),
-            pl.BlockSpec((1, G, 1), lambda bh, s: (bh, s, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((B * Hkv, ns * G, D), jnp.float32),
-            jax.ShapeDtypeStruct((B * Hkv, ns * G, 1), jnp.float32),
-            jax.ShapeDtypeStruct((B * Hkv, ns * G, 1), jnp.float32),
-        ],
+        out_specs=out_specs,
+        out_shape=out_shape(B * Hkv, ns),
         interpret=interpret,
     )(qg, kt, vt, kst, vst, mk)
-
-    o = o.reshape(B * Hkv, ns, G, D)
-    m = m.reshape(B * Hkv, ns, G, 1)
-    l = l.reshape(B * Hkv, ns, G, 1)
-    m_all = jnp.max(m, axis=1, keepdims=True)
-    w = jnp.exp(m - m_all)
-    l_all = jnp.sum(l * w, axis=1)
-    out = jnp.sum(o * w, axis=1) / jnp.maximum(l_all, 1e-30)
-    return out.reshape(B, Hkv, G, D).reshape(B, Hq, D).astype(q.dtype)
+    return merge_partials(o, m, l).reshape(B, Hq, D).astype(q.dtype)
 
 
 # ===========================================================================
 # Pallas: quantized paged split-KV decode attention
 # ===========================================================================
-def _quant_paged_decode_kernel(pt_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
-                               mask_ref, o_ref, m_ref, l_ref, *,
-                               sm_scale: float):
-    j = pl.program_id(2)
+def _quant_paged_decode_kernel(pt_ref, words_ref, q_ref, k_ref, v_ref,
+                               ks_ref, vs_ref, o_ref, m_ref, l_ref, *,
+                               sm_scale: float, pages_per_block: int,
+                               page_size: int):
+    b, s, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
 
     @pl.when(j == 0)
     def _init():
-        o_ref[0] = jnp.zeros_like(o_ref[0])
-        m_ref[0] = jnp.full_like(m_ref[0], NEG_INF)
-        l_ref[0] = jnp.zeros_like(l_ref[0])
+        init_partials(o_ref, m_ref, l_ref)
 
-    q = q_ref[0].astype(jnp.float32)                   # (G, D)
-    ks = ks_ref[0].astype(jnp.float32)                 # (ps, 1)
-    vs = vs_ref[0].astype(jnp.float32)
-    k = k_ref[0, :, 0, :].astype(jnp.float32) * ks     # (ps, D)
-    v = v_ref[0, :, 0, :].astype(jnp.float32) * vs
-    valid = mask_ref[0]                                # (1, ps) int32
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * sm_scale
-    s = jnp.where(valid > 0, s, NEG_INF)               # (G, ps)
-
-    m_prev = m_ref[0]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-    alpha = jnp.exp(m_prev - m_new)
-    p = jnp.exp(s - m_new)
-    l_new = l_ref[0] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-    acc = o_ref[0] * alpha + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-    o_ref[0] = acc
-    m_ref[0] = m_new
-    l_ref[0] = l_new
+    valid = page_rows_valid(words_ref, b, s * pages_per_block + j,
+                            page_size)
+    # dequantize the gathered page: (ps, Hkv, D) payload * per-row scale
+    k = k_ref[0].astype(jnp.float32) \
+        * ks_ref[0].astype(jnp.float32)[:, :, None]
+    v = v_ref[0].astype(jnp.float32) \
+        * vs_ref[0].astype(jnp.float32)[:, :, None]
+    for g in range(q_ref.shape[1]):
+        o_ref[0, 0, g], m_ref[0, 0, g], l_ref[0, 0, g] = softmax_update(
+            q_ref[0, g], k, v, valid, o_ref[0, 0, g], m_ref[0, 0, g],
+            l_ref[0, 0, g], sm_scale=sm_scale)
 
 
 def quant_paged_decode_attention_splitkv(q, k_pages, v_pages, k_scales,
@@ -288,65 +270,17 @@ def quant_paged_decode_attention_splitkv(q, k_pages, v_pages, k_scales,
     k/v_scales: (P, ps, Hkv); page_table: (B, NP) int32;
     kv_mask: (B, NP * ps) bool. Each program dequantizes exactly one
     gathered physical page."""
-    B, Hq, D = q.shape
+    B, _, D = q.shape
     ps, Hkv = k_pages.shape[1], k_pages.shape[2]
-    NP = page_table.shape[1]
-    G = Hq // Hkv
-    pb = max(1, min(pages_per_block, NP))
-    NPp = -(-NP // pb) * pb
-    ns = NPp // pb
-
-    qg = q.reshape(B, Hkv, G, D).reshape(B * Hkv, G, D)
-    mk = kv_mask.reshape(B, 1, NP * ps).astype(jnp.int32)
-    pt = page_table.astype(jnp.int32)
-    if NPp != NP:
-        pt = jnp.pad(pt, ((0, 0), (0, NPp - NP)))
-        mk = jnp.pad(mk, ((0, 0), (0, 0), (0, (NPp - NP) * ps)))
-
+    qg, pt, words, G, pb, ns = paged_prologue(q, page_table, kv_mask, ps,
+                                              Hkv, pages_per_block)
     kern = functools.partial(_quant_paged_decode_kernel,
-                             sm_scale=1.0 / math.sqrt(D))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(B * Hkv, ns, pb),
-        in_specs=[
-            pl.BlockSpec((1, G, D), lambda bh, s, j, pt: (bh, 0, 0)),
-            pl.BlockSpec((1, ps, 1, D),
-                         lambda bh, s, j, pt:
-                         (pt[bh // Hkv, s * pb + j], 0, bh % Hkv, 0)),
-            pl.BlockSpec((1, ps, 1, D),
-                         lambda bh, s, j, pt:
-                         (pt[bh // Hkv, s * pb + j], 0, bh % Hkv, 0)),
-            pl.BlockSpec((1, ps, 1),
-                         lambda bh, s, j, pt:
-                         (pt[bh // Hkv, s * pb + j], 0, bh % Hkv)),
-            pl.BlockSpec((1, ps, 1),
-                         lambda bh, s, j, pt:
-                         (pt[bh // Hkv, s * pb + j], 0, bh % Hkv)),
-            pl.BlockSpec((1, 1, ps),
-                         lambda bh, s, j, pt: (bh // Hkv, 0, s * pb + j)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, G, D), lambda bh, s, j, pt: (bh, s, 0)),
-            pl.BlockSpec((1, G, 1), lambda bh, s, j, pt: (bh, s, 0)),
-            pl.BlockSpec((1, G, 1), lambda bh, s, j, pt: (bh, s, 0)),
-        ],
-    )
+                             sm_scale=1.0 / math.sqrt(D),
+                             pages_per_block=pb, page_size=ps)
     o, m, l = pl.pallas_call(
         kern,
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((B * Hkv, ns * G, D), jnp.float32),
-            jax.ShapeDtypeStruct((B * Hkv, ns * G, 1), jnp.float32),
-            jax.ShapeDtypeStruct((B * Hkv, ns * G, 1), jnp.float32),
-        ],
+        grid_spec=paged_grid_spec(B, G, Hkv, D, ps, ns, pb, 2, n_side=2),
+        out_shape=paged_partials_shape(B, ns, G, Hkv, D),
         interpret=interpret,
-    )(pt, qg, k_pages, v_pages, k_scales, v_scales, mk)
-
-    o = o.reshape(B * Hkv, ns, G, D)
-    m = m.reshape(B * Hkv, ns, G, 1)
-    l = l.reshape(B * Hkv, ns, G, 1)
-    m_all = jnp.max(m, axis=1, keepdims=True)
-    w = jnp.exp(m - m_all)
-    l_all = jnp.sum(l * w, axis=1)
-    out = jnp.sum(o * w, axis=1) / jnp.maximum(l_all, 1e-30)
-    return out.reshape(B, Hkv, G, D).reshape(B, Hq, D).astype(q.dtype)
+    )(pt, words, qg, k_pages, v_pages, k_scales, v_scales)
+    return paged_epilogue(o, m, l, q)

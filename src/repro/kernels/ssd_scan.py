@@ -8,10 +8,12 @@ chunk summaries. Tiling:
     grid = (B * NH, S / chunk)      (chunks sequential)
 
 Per program: x (L, hp), dt (L, 1), B/C (L, N) tiles in VMEM; the
-running state h (hp, N) lives in f32 VMEM scratch and is carried across
-the sequential chunk dim — the TPU analogue of the accumulation buffer
-in the paper's generic architecture (intermediate results stay on-chip
-until all associated calculations finish).
+per-head decay A is read from SMEM (a (1, 1) VMEM block of it would
+break the TPU block tiling); the running state h (hp, N) lives in f32
+VMEM scratch and is carried across the sequential chunk dim — the TPU
+analogue of the accumulation buffer in the paper's generic architecture
+(intermediate results stay on-chip until all associated calculations
+finish).
 """
 from __future__ import annotations
 
@@ -33,7 +35,7 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, hout_ref,
 
     x = x_ref[0].astype(jnp.float32)                  # (L, hp)
     dt = dt_ref[0].astype(jnp.float32)                # (L, 1)
-    A = a_ref[0, 0]                                   # scalar (negative)
+    A = a_ref[pl.program_id(0)]                       # scalar (negative)
     Bm = b_ref[0].astype(jnp.float32)                 # (L, N)
     Cm = c_ref[0].astype(jnp.float32)                 # (L, N)
 
@@ -42,12 +44,17 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, hout_ref,
     dt = jnp.where(pos < seq_len, dt, 0.0)
 
     dA = dt * A                                       # (L, 1)
-    a_cs = jnp.cumsum(dA, axis=0)                     # (L, 1)
+    tri = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0) >= \
+        jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    # inclusive prefix sum as a lower-triangular matmul (Mosaic has no
+    # cumsum), at full f32 precision: the decays below exponentiate it
+    a_cs = jax.lax.dot_general(tri.astype(jnp.float32), dA,
+                               (((1,), (0,)), ((), ())),
+                               precision=jax.lax.Precision.HIGHEST,
+                               preferred_element_type=jnp.float32)  # (L, 1)
 
     # intra-chunk: masked decay attention  M[t,s] = C_t.B_s e^{a_t-a_s} dt_s
     diff = a_cs - a_cs.T                              # (L, L)
-    tri = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0) >= \
-        jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
     decay = jnp.where(tri, jnp.exp(diff), 0.0)
     scores = jax.lax.dot_general(Cm, Bm, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
@@ -63,11 +70,18 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, hout_ref,
                              preferred_element_type=jnp.float32)
 
     # state update: h = e^{sum dA} h + sum_s e^{a_L - a_s} dt_s x_s B_s^T
-    decay_end = jnp.exp(a_cs[-1:] - a_cs)             # (L, 1)
+    a_end = jax.lax.slice(a_cs, (chunk - 1, 0), (chunk, 1))    # (1, 1)
+    decay_end = jnp.exp(a_end - a_cs)                 # (L, 1)
     xw = x * (dt * decay_end)                         # (L, hp)
     hupd = jax.lax.dot_general(xw, Bm, (((0,), (0,)), ((), ())),
                                preferred_element_type=jnp.float32)
-    h_scr[...] = h * jnp.exp(a_cs[-1]) + hupd         # (hp, N)
+    # chunk decay e^{a_L} as a (1, N) row, taken from a_cs's last row so
+    # it matches decay_end exactly: Mosaic cannot broadcast a (1, 1)
+    # across sublanes and lanes at once
+    last = jax.lax.broadcasted_iota(jnp.int32, (chunk, 1), 0) == chunk - 1
+    a_end_row = jnp.sum(jnp.where(last, a_cs, 0.0) * jnp.ones_like(Bm),
+                        axis=0, keepdims=True)
+    h_scr[...] = h * jnp.exp(a_end_row) + hupd        # (hp, N)
 
     y_ref[0] = y.astype(y_ref.dtype)
 
@@ -92,7 +106,8 @@ def ssd_scan_pallas(x, dt, A, B, C, *, chunk: int = 128,
 
     xt, Bt, Ct = bh(x), bh(B), bh(C)
     dtt = bh(dt[..., None])
-    At = jnp.broadcast_to(A[None, :], (b, nh)).reshape(b * nh, 1)
+    At = jnp.broadcast_to(A[None, :], (b, nh)).reshape(b * nh) \
+        .astype(jnp.float32)
     if Sp != S:
         pad = ((0, 0), (0, Sp - S)) + ((0, 0),)
         xt = jnp.pad(xt, pad)
@@ -105,7 +120,7 @@ def ssd_scan_pallas(x, dt, A, B, C, *, chunk: int = 128,
         in_specs=[
             pl.BlockSpec((1, chunk, hp), lambda i, c: (i, c, 0)),
             pl.BlockSpec((1, chunk, 1), lambda i, c: (i, c, 0)),
-            pl.BlockSpec((1, 1), lambda i, c: (i, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, chunk, N), lambda i, c: (i, c, 0)),
             pl.BlockSpec((1, chunk, N), lambda i, c: (i, c, 0)),
         ],

@@ -29,7 +29,6 @@ from repro.launch.presets import force_host_devices
 from repro.models import abstract_params
 from repro.models.layers import cross_entropy
 from repro.models.model import ModelRuntime, attn_block, norm
-from jax.experimental.shard_map import shard_map
 
 
 def lower_pp(arch: str = "chatglm3-6b", n_stages: int = 4,
@@ -75,9 +74,9 @@ def lower_pp(arch: str = "chatglm3-6b", n_stages: int = 4,
         mask = (stage_idx == n_stages - 1).astype(out_buf.dtype)
         return jax.lax.psum(out_buf * mask, "stage")
 
-    pp = shard_map(pp_inner, mesh=mesh,
-                   in_specs=(P("stage"), P(None, "data")),
-                   out_specs=P(None, "data"), check_rep=False)
+    pp = jax.shard_map(pp_inner, mesh=mesh,
+                       in_specs=(P("stage"), P(None, "data")),
+                       out_specs=P(None, "data"), check_vma=False)
 
     def loss_fn(params, tokens, labels):
         x = params["embed"].astype(rt.dtype)[tokens]      # (M, mb, S, d)

@@ -4,48 +4,29 @@
 importing this module never touches jax device state; only the dry-run
 sets ``xla_force_host_platform_device_count``.
 
-``use_mesh`` papers over the jax API churn around ambient meshes:
-``jax.sharding.set_mesh`` (new), ``jax.sharding.use_mesh`` (0.4.35+),
-or the ``with mesh:`` context (older) — whichever this jax has.
+Meshes are built with ``Auto`` axes: placement comes from the
+``repro.dist`` recipes through ``constrain``, not from sharding-in-types.
 """
 from __future__ import annotations
 
-from contextlib import contextmanager
-
 import jax
-
-
-def _axis_type_kwargs(n_axes: int) -> dict:
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
-
-
-def make_production_mesh(*, multi_pod: bool = False):
-    """16x16 (data, model) single pod or 2x16x16 (pod, data, model)."""
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_type_kwargs(len(axes)))
+from jax.sharding import AxisType
 
 
 def make_mesh(shape, axes):
     """Arbitrary mesh (elastic re-plans, tests, PP stage meshes)."""
     return jax.make_mesh(tuple(shape), tuple(axes),
-                         **_axis_type_kwargs(len(axes)))
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
-@contextmanager
+def make_production_mesh(*, multi_pod: bool = False):
+    """16x16 (data, model) single pod or 2x16x16 (pod, data, model)."""
+    if multi_pod:
+        return make_mesh((2, 16, 16), ("pod", "data", "model"))
+    return make_mesh((16, 16), ("data", "model"))
+
+
 def use_mesh(mesh):
-    """Install ``mesh`` as the ambient mesh, across jax versions.
-
-    Prefers ``jax.sharding.use_mesh`` (a context manager wherever it
-    exists); ``set_mesh`` is a plain global setter on some versions,
-    so it is deliberately not tried first."""
-    setter = getattr(jax.sharding, "use_mesh", None)
-    if setter is not None:
-        with setter(mesh):
-            yield mesh
-    else:
-        with mesh:
-            yield mesh
+    """Context manager installing ``mesh`` as the ambient mesh — the one
+    ``repro.dist.sharding.constrain`` resolves logical axes against."""
+    return jax.set_mesh(mesh)

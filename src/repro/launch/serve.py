@@ -7,24 +7,79 @@ with ``--mesh`` — a sharded slot batch over a device mesh via the
 ``repro.dist`` decode recipe. Prints tok/s, per-step latency
 percentiles, slot occupancy, prefill compile count, and any rejected
 requests.
+
+On a TPU the engine serves in bfloat16 — runtime, stored parameters,
+and the preflight/deploy models alike; on the CPU (the ``--smoke`` test
+path) in float32. :func:`build_engine` is the one engine constructor
+``main`` and ``chip_smoke.py`` share.
 """
 from __future__ import annotations
 
 import argparse
 import logging
 import time
+from typing import Optional, Sequence
 
 import numpy as np
 
 import jax
 
 from repro.configs import get_arch, smoke_config
+from repro.configs.base import ModelConfig
 from repro.core.workload.registry import resolve_arch
+from repro.kernels.dispatch import KernelPolicy
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import init_params
 from repro.models.model import ModelRuntime
 from repro.serve import (PagedServeEngine, Request, Sampler, Scheduler,
                          ServeEngine, ShardedPagedServeEngine,
                          ShardedServeEngine)
+
+
+def serve_dtype() -> str:
+    """Compute and parameter dtype: bf16 on the chip, f32 on the CPU."""
+    return "bfloat16" if jax.default_backend() == "tpu" else "float32"
+
+
+def serving_runtime(dtype: str, kv_dtype: Optional[str] = None,
+                    kernels: Optional[KernelPolicy] = None
+                    ) -> ModelRuntime:
+    return ModelRuntime(dtype=dtype, remat="none", attn_chunk=128,
+                        moe_dropless=True, kv_dtype=kv_dtype,
+                        kernels=kernels)
+
+
+def init_serving_params(cfg: ModelConfig, seed: int, dtype: str):
+    """Seeded random parameters held in ``dtype`` from the start: init
+    and cast run as one jitted program, so float32 masters never sit on
+    the device beside their ``dtype`` copies."""
+    def init(key):
+        return jax.tree.map(lambda a: a.astype(dtype), init_params(key, cfg))
+    return jax.jit(init)(jax.random.PRNGKey(seed))
+
+
+def build_engine(params, cfg: ModelConfig, rt: ModelRuntime, *,
+                 n_slots: int, max_len: int,
+                 buckets: Optional[Sequence[int]] = None,
+                 admit_width: int = 1, sampler: Optional[Sampler] = None,
+                 overflow: str = "reject", eos_id: Optional[int] = None,
+                 page_size: int = 0, page_budget: Optional[int] = None,
+                 prefix_cache: bool = True, mesh=None) -> ServeEngine:
+    """The serving engine the launcher runs: paged when ``page_size``
+    > 0, sharded over ``mesh`` when one is given."""
+    sched = Scheduler(cfg=cfg, max_len=max_len, buckets=buckets,
+                      admit_width=admit_width)
+    kw = dict(n_slots=n_slots, max_len=max_len, sampler=sampler,
+              scheduler=sched, overflow=overflow, eos_id=eos_id)
+    if page_size > 0:
+        kw.update(page_size=page_size, page_budget=page_budget,
+                  prefix_cache=prefix_cache)
+    if mesh is not None:
+        eng_cls = ShardedPagedServeEngine if page_size > 0 \
+            else ShardedServeEngine
+        return eng_cls(params, cfg, rt, mesh, **kw)
+    eng_cls = PagedServeEngine if page_size > 0 else ServeEngine
+    return eng_cls(params, cfg, rt, **kw)
 
 
 def main():
@@ -93,6 +148,8 @@ def main():
     args = ap.parse_args()
 
     logging.basicConfig(level=logging.INFO)
+    enable_compile_cache()
+    dtype = serve_dtype()
     cfg = get_arch(resolve_arch(args.arch))
     if args.smoke:
         cfg = smoke_config(cfg)
@@ -121,8 +178,7 @@ def main():
         dep = DeploymentSpec(
             n_slots=args.slots, max_len=args.max_len, buckets=buckets,
             admit_width=args.admit_width, page_size=args.page_size,
-            page_budget=args.page_budget, dtype="float32",
-            param_dtype="float32",
+            page_budget=args.page_budget, dtype=dtype, param_dtype=dtype,
             kv_dtypes=(args.kv_dtype,) if args.kv_dtype else (),
             mesh=mesh_sizes, hbm_gb=args.hbm_gb)
         drep = deploy_preflight(cfg, scenario, deployment=dep)
@@ -156,7 +212,7 @@ def main():
             page_size=args.page_size or None,
             page_budget=args.page_budget, mesh=mesh_sizes,
             hbm_gb=args.hbm_gb, kv_dtype=args.kv_dtype,
-            dtype="float32")   # matches the runtime constructed below
+            dtype=dtype)   # matches the runtime constructed below
         print(f"preflight: predicted peak "
               f"{cap.peak_bytes / 2**30:.3f} GiB / "
               f"{cap.hbm_bytes / 2**30:.1f} GiB per device "
@@ -171,29 +227,21 @@ def main():
                 f"the {cap.hbm_bytes / 2**30:.1f} GiB budget — shrink "
                 f"--slots/--max-len, page the cache, or shard wider")
 
-    rt = ModelRuntime(dtype="float32", remat="none", attn_chunk=128,
-                      moe_dropless=True, kv_dtype=args.kv_dtype)
-    params = init_params(jax.random.PRNGKey(args.seed), cfg)
-
-    sched = Scheduler(cfg=cfg, max_len=args.max_len, buckets=buckets,
-                      admit_width=args.admit_width)
-    sampler = Sampler(kind=args.sampler, temperature=args.temperature,
-                      top_k=args.top_k, seed=args.seed)
-    kw = dict(n_slots=args.slots, max_len=args.max_len, sampler=sampler,
-              scheduler=sched, overflow=args.overflow, eos_id=args.eos)
-    if args.page_size > 0:
-        kw.update(page_size=args.page_size, page_budget=args.page_budget,
-                  prefix_cache=args.prefix_cache)
+    rt = serving_runtime(dtype, kv_dtype=args.kv_dtype)
+    params = init_serving_params(cfg, args.seed, dtype)
+    mesh = None
     if args.mesh:
         from repro.launch.mesh import make_mesh
         d, m = (int(x) for x in args.mesh.split("x"))
         mesh = make_mesh((d, m), ("data", "model"))
-        eng_cls = ShardedPagedServeEngine if args.page_size > 0 \
-            else ShardedServeEngine
-        eng = eng_cls(params, cfg, rt, mesh, **kw)
-    else:
-        eng_cls = PagedServeEngine if args.page_size > 0 else ServeEngine
-        eng = eng_cls(params, cfg, rt, **kw)
+    eng = build_engine(
+        params, cfg, rt, n_slots=args.slots, max_len=args.max_len,
+        buckets=buckets, admit_width=args.admit_width,
+        sampler=Sampler(kind=args.sampler, temperature=args.temperature,
+                        top_k=args.top_k, seed=args.seed),
+        overflow=args.overflow, eos_id=args.eos, page_size=args.page_size,
+        page_budget=args.page_budget, prefix_cache=args.prefix_cache,
+        mesh=mesh)
 
     rng = np.random.default_rng(args.seed)
     if scenario is not None:
@@ -229,7 +277,7 @@ def main():
     print(f"  step latency p50/p99 {p50:.1f}/{p99:.1f} ms; slot "
           f"occupancy {st.occupancy(args.slots):.2f}; prefill compiles "
           f"{st.prefill_compiles} (bound "
-          f"{sched.max_prefill_compiles() or 'unbounded'}); "
+          f"{eng.scheduler.max_prefill_compiles() or 'unbounded'}); "
           f"forced prompt tokens {st.forced_tokens}")
     print(f"  kv cache {eng.kv_cache_bytes() / 2**20:.1f} MiB, "
           f"utilization {st.kv_utilization:.2f}, max in-flight "

@@ -291,7 +291,7 @@ def test_capture_records_live_kernels():
                        q, kp, kp, pt, mk)
     assert len(caps) == 1
     cap = caps[0]
-    assert cap.num_scalar_prefetch == 1
+    assert cap.num_scalar_prefetch == 2     # page table + row-mask words
     assert len(cap.grid) == 3
     # no scratch — the race exemption comes from the output-ref reads
     assert not cap.scratch_shapes and declares_accumulation(cap)
@@ -369,9 +369,7 @@ def test_scan_jaxpr_flags_host_sync():
 
 
 def test_scan_jaxpr_flags_f64():
-    from jax.experimental import enable_x64
-
-    with enable_x64():
+    with jax.enable_x64():
         closed = jax.make_jaxpr(
             lambda x: x.astype(jnp.float64) * 2)(jnp.ones((4,)))
     found = scan_jaxpr(closed, label="t", rt_dtype="float32")

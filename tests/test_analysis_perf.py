@@ -293,7 +293,7 @@ def test_param_sharding_tree_records_leaf_paths():
     axes = {"w": ("vocab", "embed")}     # vocab -> model(4): 6 % 4 != 0
     reset_spec_drops()
     param_sharding_tree(axes, RECIPES["WS"],
-                        AbstractMesh((("data", 2), ("model", 4))),
+                        AbstractMesh((2, 4), ("data", "model")),
                         abstract)
     drops = [d for d in spec_drops() if d.reason == "indivisible"]
     assert len(drops) == 1 and "'w'" in drops[0].path

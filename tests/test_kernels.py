@@ -155,6 +155,8 @@ def test_sort_by_expert_roundtrip_property(T, E, bm, seed):
       leaks data into an expert's group);
     * each row lands in a block whose ``block_expert`` matches its
       routed expert (the scalar-prefetch contract of the kernel);
+    * every block, spare ones included, names a real expert (the chip
+      bounds-checks the weight fetch);
     * destination slots are unique (``inv`` is injective).
     """
     ks = jax.random.split(jax.random.PRNGKey(seed), 2)
@@ -176,6 +178,7 @@ def test_sort_by_expert_roundtrip_property(T, E, bm, seed):
     assert np.all(x_pad[~hit] == 0.0)
     # each row's destination block streams that row's expert weights
     np.testing.assert_array_equal(block_expert[inv // bm], eorn)
+    assert np.all((block_expert >= 0) & (block_expert < E))
 
 
 def test_grouped_gemm_empty_group():
@@ -202,3 +205,27 @@ def test_rmsnorm(R, d, br, dtype):
     np.testing.assert_allclose(
         np.asarray(out, np.float32), np.asarray(want, np.float32),
         atol=_tol(dtype), rtol=_tol(dtype))
+
+
+# ------------------------------------------------------------ ops wrappers
+@pytest.mark.parametrize("backend,interpret", [("cpu", True),
+                                               ("tpu", False),
+                                               ("gpu", None)])
+def test_kernels_interpret_only_on_cpu(monkeypatch, backend, interpret):
+    """Pallas kernels compile on the TPU, interpret on the CPU, and are
+    refused elsewhere rather than interpreted on an accelerator."""
+    from repro.kernels import ops
+    monkeypatch.setattr(ops.jax, "default_backend", lambda: backend)
+    if interpret is None:
+        with pytest.raises(RuntimeError, match="gpu"):
+            ops._interpret()
+    else:
+        assert ops._interpret() is interpret
+
+
+@pytest.mark.parametrize("n,block,want", [(1408, 512, 128), (2048, 512, 512),
+                                          (1536, 512, 512), (768, 512, 384),
+                                          (100, 512, 100)])
+def test_fit_block_divides_width(n, block, want):
+    from repro.kernels.ops import _fit_block
+    assert _fit_block(n, block) == want

@@ -131,6 +131,28 @@ def test_recipe_sharded_train_step_runs():
     """)
 
 
+def test_constrain_applies_under_use_mesh():
+    """Under ``use_mesh`` + ``axis_rules`` a logical constraint reaches
+    the compiled program; outside a mesh it is the identity."""
+    _run("""
+    import jax, jax.numpy as jnp
+    from repro.dist.sharding import DECODE_RECIPE, axis_rules, constrain
+    from repro.launch.mesh import make_mesh, use_mesh
+
+    mesh = make_mesh((1, 4), ("data", "model"))
+    f = jax.jit(lambda x: constrain(x * 2, ("batch", "ffn")))
+    x = jnp.ones((2, 8))
+    with use_mesh(mesh), axis_rules(DECODE_RECIPE):
+        text = f.lower(x).as_text()
+        y = f(x)
+    assert "sharding_constraint" in text or "Sharding" in text, text
+    assert y.sharding.spec == jax.sharding.PartitionSpec(None, "model"), \
+        y.sharding
+    with axis_rules(DECODE_RECIPE):
+        assert "Sharding" not in f.lower(x).as_text()
+    """, devices=4)
+
+
 def test_sharded_serve_engine_token_parity():
     """ShardedServeEngine (decode recipe: weights TP over `model`, slot
     batch over `data`) must serve token-for-token the same output as the

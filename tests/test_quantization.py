@@ -164,6 +164,21 @@ def test_logit_parity_within_tol(arch):
     assert j["tol"] == QUANT_PARITY_TOL
 
 
+@pytest.mark.parametrize("bad", [np.nan, np.inf])
+@pytest.mark.parametrize("side", ["ref", "test"])
+def test_compare_logits_non_finite_is_never_within_tol(bad, side):
+    """One non-finite logit at one decode step, after finite prefill
+    logits, must not vanish from the deviation."""
+    from repro.serve.parity import compare_logits
+    rng = np.random.default_rng(0)
+    ref = [rng.normal(size=(2, 8)).astype(np.float32) for _ in range(4)]
+    test = [r.copy() for r in ref]
+    (ref if side == "ref" else test)[2][1, 3] = bad
+    rep = compare_logits(ref, test)
+    assert not np.isfinite(rep.max_logit_dev)
+    assert not rep.within_tol
+
+
 # ===================================================================
 # Paged vs contiguous int8: bit-identical token streams
 # ===================================================================

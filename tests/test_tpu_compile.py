@@ -1,0 +1,145 @@
+"""Compile the Pallas kernels and the paged decode step for a described
+TPU v5e chip, at real model widths.
+
+Nothing runs: the chip's compiler is installed here and compiles for a
+chip that is described, not attached, so what it refuses (block tiling,
+VMEM, HBM fit) is caught without one. The topology is described inside
+a module fixture — never at import — because only one process at a time
+may load the TPU library.
+"""
+from __future__ import annotations
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from repro.configs import get_arch
+from repro.kernels import ops
+from repro.kernels.dispatch import KernelPolicy
+from repro.models import abstract_params, decode_step_paged, page_count
+from repro.models.model import ModelRuntime, paged_cache_spec
+
+HBM_BYTES = 16 * 2**30                  # one TPU v5e chip
+
+# minicpm-2b serving widths (the chip smoke's configuration)
+SLOTS, MAX_LEN, PAGE = 8, 1024, 16
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("TPU_LOG_DIR", "disabled")
+        try:
+            topo = topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+        except Exception as e:   # no TPU compiler in this installation
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        # a described-chip compile is written to the persistent cache
+        # but cannot be read back without the chip: keep it out
+        was = jax.config.jax_enable_compilation_cache
+        jax.config.update("jax_enable_compilation_cache", False)
+        compilation_cache.reset_cache()
+        # the ops wrappers pick interpret mode from the (CPU) backend;
+        # steer them to Mosaic, and drop traces made under either mode
+        mp.setattr(ops, "_interpret", lambda: False)
+        jax.clear_caches()
+        yield SingleDeviceSharding(topo.devices[0])
+        jax.clear_caches()
+        jax.config.update("jax_enable_compilation_cache", was)
+
+
+def _shapes(sharding, *specs):
+    return [jax.ShapeDtypeStruct(s, jnp.dtype(d), sharding=sharding)
+            for s, d in specs]
+
+
+def _kernel_case(name):
+    """(jitted op, operand specs, static kwargs) at real widths."""
+    bf, i8, f32, i32 = "bfloat16", "int8", "float32", "int32"
+    # minicpm-2b: 36 heads (MHA), head_dim 64, d_model 2304
+    B, H, D, W = SLOTS, 36, 64, MAX_LEN
+    NP = page_count(W, PAGE)
+    P = SLOTS * NP + 1
+    paged = [((B, H, D), bf), ((P, PAGE, H, D), bf), ((P, PAGE, H, D), bf),
+             ((B, NP), i32), ((B, NP * PAGE), "bool")]
+    cases = {
+        "paged_decode_attention": (ops.paged_decode_attention, paged, {}),
+        "quant_paged_decode_attention": (
+            ops.quant_paged_decode_attention,
+            [paged[0], ((P, PAGE, H, D), i8), ((P, PAGE, H, D), i8),
+             ((P, PAGE, H), bf), ((P, PAGE, H), bf), paged[3], paged[4]],
+            {}),
+        "decode_attention": (
+            ops.decode_attention,
+            [((B, H, D), bf), ((B, W, H, D), bf), ((B, W, H, D), bf),
+             ((B, W), "bool")], {}),
+        "quant_decode_attention": (
+            ops.quant_decode_attention,
+            [((B, H, D), bf), ((B, W, H, D), i8), ((B, W, H, D), i8),
+             ((B, W, H), bf), ((B, W, H), bf), ((B, W), "bool")], {}),
+        "flash_attention": (
+            ops.flash_attention,
+            [((1, 512, H, D), bf)] * 3, {}),
+        "rmsnorm": (ops.rmsnorm, [((B * 512, 2304), bf), ((2304,), f32)],
+                    {}),
+        "quant_matmul": (
+            ops.quant_matmul,
+            [((512, 2304), bf), ((2304, 5760), i8), ((5760,), f32)], {}),
+        # mamba2-1.3b: 64 SSD heads of 64, d_state 128, chunk 256
+        "ssd_scan": (
+            ops.ssd_scan,
+            [((1, 512, 64, 64), bf), ((1, 512, 64), f32), ((64,), f32),
+             ((1, 512, 64, 128), bf), ((1, 512, 64, 128), bf)],
+            {"chunk": 256}),
+        # qwen2-moe-a2.7b: 60 experts of width 1408 over d_model 2048
+        "moe_gemm": (
+            ops.moe_grouped_matmul,
+            [((512, 2048), bf), ((60, 2048, 1408), bf), ((512,), i32)],
+            {"n_experts": 60}),
+    }
+    return cases[name]
+
+
+@pytest.mark.parametrize("name", [
+    "paged_decode_attention", "quant_paged_decode_attention",
+    "decode_attention", "quant_decode_attention", "flash_attention",
+    "rmsnorm", "quant_matmul", "ssd_scan", "moe_gemm"])
+def test_kernel_lowers_to_mosaic_for_v5e(one_chip, name):
+    fn, specs, kw = _kernel_case(name)
+    compiled = fn.lower(*_shapes(one_chip, *specs), **kw).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("policy", ["xla", "pallas"])
+def test_paged_decode_step_fits_one_v5e(one_chip, policy):
+    """One bf16 decode step of full-width minicpm-2b over the chip
+    smoke's paged pool fits the chip's HBM, under either kernel policy
+    the smoke serves with."""
+    cfg = get_arch("minicpm-2b")
+    kernels = None
+    if policy == "pallas":
+        kernels = KernelPolicy(prefill_attention="pallas",
+                               paged_decode_attention="pallas",
+                               rmsnorm="pallas")
+    rt = ModelRuntime(dtype="bfloat16", remat="none", kernels=kernels)
+    n_pages = SLOTS * page_count(MAX_LEN, PAGE) + 1
+    spec = paged_cache_spec(cfg, SLOTS, n_pages, PAGE, MAX_LEN, "bfloat16")
+    cache = {k: jax.ShapeDtypeStruct(s, jnp.dtype(d), sharding=one_chip)
+             for k, (s, d) in spec.items()}
+    params = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        abstract_params(cfg, "bfloat16"))
+    tokens = jax.ShapeDtypeStruct((SLOTS,), jnp.int32, sharding=one_chip)
+    step = jax.jit(lambda p, c, t: decode_step_paged(
+        p, cfg, c, t, rt, page_size=PAGE, window=MAX_LEN))
+    compiled = step.lower(params, cache, tokens).compile()
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert total < HBM_BYTES, f"{total / 2**30:.2f} GiB"
+    assert ("tpu_custom_call" in compiled.as_text()) == (policy == "pallas")
