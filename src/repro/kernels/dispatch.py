@@ -255,10 +255,12 @@ def dispatch(op: str, policy: Optional[KernelPolicy], *arrays: Any,
             f"registered: {sorted(table)}")
     merged = {**kwargs, **pol.params_for(op)}
     fn = table[impl]
-    if impl != "xla":
-        fn = _ref_backward(op, fn, merged)
-        return fn(*arrays)
-    return fn(*arrays, **merged)
+    # the op's name scopes every instruction it lowers to (``op_name``
+    # metadata in the compiled HLO), so a device trace names the kernel
+    with jax.named_scope(op):
+        if impl != "xla":
+            return _ref_backward(op, fn, merged)(*arrays)
+        return fn(*arrays, **merged)
 
 
 # ===========================================================================

@@ -141,6 +141,7 @@ def abstract_params(cfg: ModelConfig, dtype: Optional[str] = None):
 # ===========================================================================
 # Blocks
 # ===========================================================================
+@jax.named_scope("mlp")
 def _mlp(p: Dict[str, jax.Array], h: jax.Array, cfg: ModelConfig) -> jax.Array:
     if cfg.mlp == "swiglu":
         z = swiglu(h @ p["wg"].astype(h.dtype), h @ p["wi"].astype(h.dtype))
@@ -676,8 +677,9 @@ def _attn_decode_one_paged(p, x, kp, vp, pt, pos, window: int,
     q, k = L.apply_rope(q, k, posv, cfg)
     row = (pos % W).astype(jnp.int32)                    # (B,)
     phys = jnp.take_along_axis(pt, (row // ps)[:, None], axis=1)[:, 0]
-    kp = kp.at[phys, row % ps].set(k[:, 0].astype(kp.dtype))
-    vp = vp.at[phys, row % ps].set(v[:, 0].astype(vp.dtype))
+    with jax.named_scope("kv_write"):
+        kp = kp.at[phys, row % ps].set(k[:, 0].astype(kp.dtype))
+        vp = vp.at[phys, row % ps].set(v[:, 0].astype(vp.dtype))
     Wp = pt.shape[1] * ps
     ar = jnp.arange(Wp)[None, :]
     mask = (ar <= pos[:, None]) & (ar < W)               # (B, Wp)
@@ -715,12 +717,13 @@ def _attn_decode_one_paged_q(p, x, kp, vp, ks, vs, pt, pos, window: int,
     q, k = L.apply_rope(q, k, posv, cfg)
     row = (pos % W).astype(jnp.int32)                    # (B,)
     phys = jnp.take_along_axis(pt, (row // ps)[:, None], axis=1)[:, 0]
-    kq, ksc = quantize_rows(k[:, 0])                     # (B,Hkv,hd)/(B,Hkv)
-    vq, vsc = quantize_rows(v[:, 0])
-    kp = kp.at[phys, row % ps].set(kq)
-    vp = vp.at[phys, row % ps].set(vq)
-    ks = ks.at[phys, row % ps].set(ksc.astype(ks.dtype))
-    vs = vs.at[phys, row % ps].set(vsc.astype(vs.dtype))
+    with jax.named_scope("kv_write"):
+        kq, ksc = quantize_rows(k[:, 0])                 # (B,Hkv,hd)/(B,Hkv)
+        vq, vsc = quantize_rows(v[:, 0])
+        kp = kp.at[phys, row % ps].set(kq)
+        vp = vp.at[phys, row % ps].set(vq)
+        ks = ks.at[phys, row % ps].set(ksc.astype(ks.dtype))
+        vs = vs.at[phys, row % ps].set(vsc.astype(vs.dtype))
     Wp = pt.shape[1] * ps
     ar = jnp.arange(Wp)[None, :]
     mask = (ar <= pos[:, None]) & (ar < W)               # (B, Wp)

@@ -27,10 +27,21 @@ Three seed bugs are fixed here, each with a regression test:
   a hard-coded token 0. Admission and decode both route through one
   seeded :class:`~repro.serve.sampling.Sampler` (greedy / temperature /
   top-k), with EOS and per-request stop-token termination.
+
+Each phase of a step runs under a ``jax.profiler.TraceAnnotation``
+named ``serve.<phase>`` (admit, prefill, prefill_fetch, scatter, splice,
+decode, wait, fetch, sample, release). With the profiler on they land
+on the host plane, on the clock the device planes share, so a device
+trace says which phase the host was in while the chip sat idle; with it
+off each costs one context-manager entry and builds no metadata.
+``serve.wait`` (the step program still running) and ``serve.fetch``
+(the device-to-host copy of its logits) split what was one
+``np.asarray``.
 """
 from __future__ import annotations
 
 import logging
+import time
 from collections import Counter
 from contextlib import nullcontext
 from dataclasses import dataclass, field
@@ -40,6 +51,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
 from repro.configs.base import ModelConfig
 from repro.models import (cache_token_budget, decode_step, init_cache,
@@ -61,6 +73,10 @@ class Request:
     done: bool = False
     finish_reason: Optional[str] = None  # length | stop | rejected: <why>
     truncated: bool = False              # overflow='truncate' shrank budget
+    # time.perf_counter() when queued by submit and when admitted (taken
+    # off the queue for prefill or a prefix hit)
+    t_queued: float = float("nan")
+    t_admitted: float = float("nan")
 
 
 @dataclass
@@ -68,7 +84,6 @@ class EngineStats:
     """Live counters the benchmark and the compile-count tests read."""
 
     prefill_traces: Counter = field(default_factory=Counter)  # (len, width)
-    decode_traces: int = 0
     prefills: int = 0          # prefill *calls* (>= admissions / width)
     prefill_tokens: int = 0    # tokens pushed through prefill (width * P)
     steps: int = 0             # decode steps executed
@@ -215,7 +230,6 @@ class ServeEngine:
         stats = self.stats
 
         def _step_fn(p, cache, tokens):
-            stats.decode_traces += 1          # trace-time side effect
             return self._decode(p, cache, tokens)
 
         def _prefill_fn(p, toks, lengths):
@@ -255,6 +269,11 @@ class ServeEngine:
         """Called when the request in ``slot`` retires (paged engine
         frees its pages here)."""
 
+    def _head_fits(self) -> bool:
+        """Whether the head of the queue can be admitted now (the paged
+        engine waits on its page budget)."""
+        return True
+
     def kv_cache_bytes(self) -> int:
         """Device bytes held by the KV cache (contiguous or paged),
         including the quantization scale side-bands under
@@ -284,7 +303,7 @@ class ServeEngine:
             self._reject(req, "empty prompt")
             return
         if req.max_new_tokens <= budget:
-            self.queue.append(req)
+            self._enqueue(req)
             return
         why = (f"prompt_len={S} + max_new_tokens={req.max_new_tokens} "
                f"> max_len={self.max_len}")
@@ -296,9 +315,13 @@ class ServeEngine:
                         req.rid, why, budget)
             req.max_new_tokens = budget
             req.truncated = True
-            self.queue.append(req)
+            self._enqueue(req)
             return
         self._reject(req, why)
+
+    def _enqueue(self, req: Request):
+        req.t_queued = time.perf_counter()
+        self.queue.append(req)
 
     def _reject(self, req: Request, why: str):
         log.warning("rid=%d rejected: %s", req.rid, why)
@@ -309,8 +332,11 @@ class ServeEngine:
     # ---------------------------------------------------------------- admit
     def _admit(self):
         free = [i for i, r in enumerate(self.slots) if r is None]
-        while free and self.queue:
+        while free and self.queue and self._head_fits():
             group, plan = self._next_group(len(free))
+            now = time.perf_counter()
+            for req in group:
+                req.t_admitted = now
             slots = free[: len(group)]
             free = free[len(group):]
             self._admit_group(group, plan, slots)
@@ -332,28 +358,34 @@ class ServeEngine:
         returns the single-call cache + per-row logits."""
         width = max(self.scheduler.admit_width, len(group))
         P = plan.prefill_len
-        toks = np.zeros((width, P), np.int32)
-        lengths = np.ones((width,), np.int32)
-        for j, req in enumerate(group):
-            if plan.mode == "pad":
-                toks[j, : len(req.prompt)] = req.prompt
-                lengths[j] = len(req.prompt)
-            else:                            # chunk: exact prefix
-                toks[j] = req.prompt[:P]
-                lengths[j] = P
-        with self._ctx():
-            single, logits = self._prefill(
-                self.params, jnp.asarray(toks), jnp.asarray(lengths))
+        # TraceMe metadata splits at commas: ids are joined by spaces
+        meta = ({"rids": " ".join(str(r.rid) for r in group), "bucket": P}
+                if TraceAnnotation.is_enabled() else {})
+        with TraceAnnotation("serve.prefill", **meta):
+            toks = np.zeros((width, P), np.int32)
+            lengths = np.ones((width,), np.int32)
+            for j, req in enumerate(group):
+                if plan.mode == "pad":
+                    toks[j, : len(req.prompt)] = req.prompt
+                    lengths[j] = len(req.prompt)
+                else:                            # chunk: exact prefix
+                    toks[j] = req.prompt[:P]
+                    lengths[j] = P
+            with self._ctx():
+                single, logits = self._prefill(
+                    self.params, jnp.asarray(toks), jnp.asarray(lengths))
         self.stats.prefills += 1
         self.stats.prefill_tokens += width * P
-        return single, np.asarray(logits)
+        with TraceAnnotation("serve.prefill_fetch"):
+            return single, np.asarray(logits)
 
     def _admit_group(self, group: List[Request], plan: AdmissionPlan,
                      slots: List[int]):
         single, logits_np = self._prefill_group(group, plan)
-        self.cache = _splice(self.cache, single, slots,
-                             rows=range(len(group)),
-                             axes=self._cache_axes())
+        with TraceAnnotation("serve.splice"):
+            self.cache = _splice(self.cache, single, slots,
+                                 rows=range(len(group)),
+                                 axes=self._cache_axes())
         for j, (req, slot) in enumerate(zip(group, slots)):
             self._finish_admit(req, slot, plan, logits_np[j])
 
@@ -400,30 +432,38 @@ class ServeEngine:
             self.slots[slot] = None
             self._tails[slot] = []
             self._rngs[slot] = None
-            self._release_slot(slot)
+            with TraceAnnotation("serve.release"):
+                self._release_slot(slot)
 
     def step(self) -> int:
         """One engine iteration: admit new requests, decode one token
         for every active slot. Returns the number of active slots."""
-        self._admit()
+        with TraceAnnotation("serve.admit"):
+            self._admit()
         active = [i for i, r in enumerate(self.slots) if r is not None]
         if not active:
             return 0
         self.stats.live_token_steps += self._live_tokens(active)
         self.stats.alloc_token_steps += self._allocated_tokens(active)
         self.stats.max_active = max(self.stats.max_active, len(active))
-        with self._ctx():
+        meta = ({"slots": " ".join(map(str, active))}
+                if TraceAnnotation.is_enabled() else {})
+        with TraceAnnotation("serve.decode", **meta), self._ctx():
             self.cache, logits = self._step(
                 self.params, self.cache, jnp.asarray(self.last_tokens))
-        logits_np = np.asarray(logits)
-        for slot in active:
-            self._host_pos[slot] += 1
-            if self._tails[slot]:
-                # chunked prefill tail: force the next prompt token
-                self.last_tokens[slot] = self._tails[slot].pop(0)
-                self.stats.forced_tokens += 1
-            else:
-                self._emit(slot, logits_np[slot])
+        with TraceAnnotation("serve.wait"):
+            jax.block_until_ready(logits)
+        with TraceAnnotation("serve.fetch"):
+            logits_np = np.asarray(logits)
+        with TraceAnnotation("serve.sample"):
+            for slot in active:
+                self._host_pos[slot] += 1
+                if self._tails[slot]:
+                    # chunked prefill tail: force the next prompt token
+                    self.last_tokens[slot] = self._tails[slot].pop(0)
+                    self.stats.forced_tokens += 1
+                else:
+                    self._emit(slot, logits_np[slot])
         self.stats.steps += 1
         self.stats.occupancy_sum += len(active)
         return len(active)

@@ -46,6 +46,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
 from repro.models.model import (PAGED_CACHE_AXES, decode_step_paged,
                                 init_paged_cache, page_count,
@@ -358,15 +359,9 @@ class PagedServeEngine(ServeEngine):
         super().submit(req)
 
     # ---------------------------------------------------------------- admit
-    def _admit(self):
-        free = [i for i, r in enumerate(self.slots) if r is None]
-        while free and self.queue:
-            if not self.pages.can_allocate(self._admit_need(self.queue[0])):
-                break                  # head of line waits for pages
-            group, plan = self._next_group(len(free))
-            slots = free[: len(group)]
-            free = free[len(group):]
-            self._admit_group(group, plan, slots)
+    def _head_fits(self) -> bool:
+        # head of line waits for pages
+        return self.pages.can_allocate(self._admit_need(self.queue[0]))
 
     def _next_group(self, n_free: int):
         """Same-plan grouping as the base engine, additionally gated on
@@ -393,11 +388,12 @@ class PagedServeEngine(ServeEngine):
             # pure-SSM: state cache, nothing pages — splice exactly the
             # leaves prefill produced (the page table rides untouched)
             single, logits_np = self._prefill_group(group, plan)
-            names = [n for n in self.cache if n in single]
-            sub = _splice({n: self.cache[n] for n in names},
-                          {n: single[n] for n in names}, slots,
-                          rows=range(len(group)), axes=PAGED_CACHE_AXES)
-            self.cache = dict(self.cache, **sub)
+            with TraceAnnotation("serve.splice"):
+                names = [n for n in self.cache if n in single]
+                sub = _splice({n: self.cache[n] for n in names},
+                              {n: single[n] for n in names}, slots,
+                              rows=range(len(group)), axes=PAGED_CACHE_AXES)
+                self.cache = dict(self.cache, **sub)
             for j, (req, slot) in enumerate(zip(group, slots)):
                 self._finish_admit(req, slot, plan, logits_np[j])
             return
@@ -428,8 +424,9 @@ class PagedServeEngine(ServeEngine):
                                         req.max_new_tokens, ps)
         private = self.pages.alloc(need - len(shared))
         self._slot_pages[slot] = (shared, private)
-        self._set_page_table([slot], [shared + private])
-        self.cache["pos"] = self.cache["pos"].at[slot].set(shared_len)
+        with TraceAnnotation("serve.splice"):
+            self._set_page_table([slot], [shared + private])
+            self.cache["pos"] = self.cache["pos"].at[slot].set(shared_len)
         self.stats.prefix_hits += 1
         self.stats.prefix_hit_tokens += shared_len
         plan = AdmissionPlan("chunk", shared_len)
@@ -443,38 +440,31 @@ class PagedServeEngine(ServeEngine):
         slots = [slot for _, slot in pairs]
         single, logits_np = self._prefill_group(group, plan)
 
-        n_scatter = page_count(min(P, W), ps)
-        width = max(self.scheduler.admit_width, len(pairs))
-        page_ids = np.zeros((width, n_scatter), np.int32)   # pads -> null
-        held: List[List[int]] = []
-        for j, (req, _) in enumerate(pairs):
-            pages = self.pages.alloc(self._admit_need(req, plan))
-            page_ids[j] = pages[:n_scatter]
-            held.append(pages)
-        with self._ctx():
-            if "ks" in self.cache:
-                # int8 KV: the prefill cache leaves are already
-                # quantized — scatter payload + scale side-bands
-                kp, vp, ksp, vsp = self._scatter(
-                    self.cache["kp"], self.cache["vp"],
-                    self.cache["ks"], self.cache["vs"],
-                    single["k"], single["v"],
-                    single["ks"], single["vs"], jnp.asarray(page_ids))
-                self.cache = dict(self.cache, kp=kp, vp=vp,
-                                  ks=ksp, vs=vsp)
-            else:
-                kp, vp = self._scatter(self.cache["kp"], self.cache["vp"],
-                                       single["k"], single["v"],
-                                       jnp.asarray(page_ids))
-                self.cache = dict(self.cache, kp=kp, vp=vp)
-
-        # per-slot contiguous leaves (pos + recurrent state) splice as
-        # in the fixed engine — only the KV rows page
-        names = [n for n in ("pos", "conv", "ssm") if n in self.cache]
-        sub = _splice({n: self.cache[n] for n in names},
-                      {n: single[n] for n in names}, slots,
-                      rows=range(len(pairs)), axes=PAGED_CACHE_AXES)
-        self.cache = dict(self.cache, **sub)
+        with TraceAnnotation("serve.scatter"):
+            n_scatter = page_count(min(P, W), ps)
+            width = max(self.scheduler.admit_width, len(pairs))
+            page_ids = np.zeros((width, n_scatter), np.int32)  # pads->null
+            held: List[List[int]] = []
+            for j, (req, _) in enumerate(pairs):
+                pages = self.pages.alloc(self._admit_need(req, plan))
+                page_ids[j] = pages[:n_scatter]
+                held.append(pages)
+            with self._ctx():
+                if "ks" in self.cache:
+                    # int8 KV: the prefill cache leaves are already
+                    # quantized — scatter payload + scale side-bands
+                    kp, vp, ksp, vsp = self._scatter(
+                        self.cache["kp"], self.cache["vp"],
+                        self.cache["ks"], self.cache["vs"],
+                        single["k"], single["v"],
+                        single["ks"], single["vs"], jnp.asarray(page_ids))
+                    self.cache = dict(self.cache, kp=kp, vp=vp,
+                                      ks=ksp, vs=vsp)
+                else:
+                    kp, vp = self._scatter(
+                        self.cache["kp"], self.cache["vp"],
+                        single["k"], single["v"], jnp.asarray(page_ids))
+                    self.cache = dict(self.cache, kp=kp, vp=vp)
 
         rows = []
         for j, (req, slot) in enumerate(pairs):
@@ -490,7 +480,15 @@ class PagedServeEngine(ServeEngine):
                 # are content-final -> publish them for sharing
                 n_full = min(len(req.prompt) // ps, len(pages))
                 self.pages.register(req.prompt, pages[:n_full])
-        self._set_page_table(slots, rows)
+        with TraceAnnotation("serve.splice"):
+            # per-slot contiguous leaves (pos + recurrent state) splice
+            # as in the fixed engine — only the KV rows page
+            names = [n for n in ("pos", "conv", "ssm") if n in self.cache]
+            sub = _splice({n: self.cache[n] for n in names},
+                          {n: single[n] for n in names}, slots,
+                          rows=range(len(pairs)), axes=PAGED_CACHE_AXES)
+            self.cache = dict(self.cache, **sub)
+            self._set_page_table(slots, rows)
         for j, (req, slot) in enumerate(pairs):
             self._finish_admit(req, slot, plan, logits_np[j])
 
