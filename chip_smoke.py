@@ -9,7 +9,7 @@ One chip runs three phases in this one process (a chip belongs to one
 process at a time):
 
   (a) device  — JAX must report a TPU, or the smoke fails at once;
-  (b) serve   — the paged engine, default all-XLA kernel policy, bf16:
+  (b) serve   — the paged engine, an explicit all-XLA kernel policy, bf16:
                 every request served to its length, token ids inside
                 the vocabulary, prefill compiles within the
                 scheduler's bound, finite teacher-forced logits;
@@ -213,7 +213,7 @@ def one_chip():
         f"parameters in {dtype}")
     reqs = make_requests(cfg)
 
-    rt_xla = serving_runtime(dtype)
+    rt_xla = serving_runtime(dtype, kernels=KernelPolicy.xla())
     eng, out_xla = serve("b", params, cfg, rt_xla, reqs, clock)
     release(eng)
 
@@ -241,6 +241,7 @@ def four_chips():
     import jax
     from repro.configs import get_arch
     from repro.dist.sharding import reset_spec_drops, spec_drops
+    from repro.kernels.dispatch import KernelPolicy
     from repro.launch.mesh import make_mesh
     from repro.launch.serve import (init_serving_params, serve_dtype,
                                     serving_runtime)
@@ -249,7 +250,8 @@ def four_chips():
     clock = CompileClock()
     cfg = get_arch(ARCH)
     dtype = serve_dtype()
-    rt = serving_runtime(dtype)
+    # XLA on both engines: the phase compares sharded with one device
+    rt = serving_runtime(dtype, kernels=KernelPolicy.xla())
     reqs = make_requests(cfg)
     prompts, tf = teacher_forcing(reqs)
 

@@ -17,9 +17,11 @@ kernel:
   revisit-without-carry bug, not a data race in the CUDA sense — the
   second visit silently overwrites the first (``kernel-write-race``);
 * **VMEM budget** — the double-buffered per-block footprint
-  (2 × (in blocks + out blocks) + scratch) must fit the per-core VMEM
-  budget, or the compiler stalls/spills where the tuner can't see it
-  (``kernel-vmem-budget``);
+  (2 × (in blocks + out blocks) + VMEM scratch) must fit the per-core
+  VMEM budget, or the compiler stalls/spills where the tuner can't see
+  it (``kernel-vmem-budget``). An operand left in HBM (``pl.ANY``,
+  which the kernel copies by its own DMAs into VMEM scratch), SMEM
+  scratch and DMA semaphores take none of it;
 * **differentiability** — the impl must either be a ``jax.custom_vjp``
   or have an xla reference to borrow a backward pass from (the
   ``dispatch._ref_backward`` contract), and the borrowed VJP must
@@ -206,6 +208,13 @@ def _eval_index_map(spec, cap: PallasCapture, ncells: int,
             for c in out]
 
 
+def _in_vmem(obj) -> bool:
+    """Does a BlockSpec or scratch shape live in VMEM? A BlockSpec with
+    no memory space is pipelined through VMEM."""
+    ms = getattr(obj, "memory_space", None)
+    return ms is None or getattr(ms, "value", ms) == "vmem"
+
+
 def check_capture(cap: PallasCapture, *, vmem_budget: int,
                   label: str) -> List[Finding]:
     findings: List[Finding] = []
@@ -255,7 +264,7 @@ def check_capture(cap: PallasCapture, *, vmem_budget: int,
     for i, spec in enumerate(cap.in_specs):
         aval = (cap.in_avals[cap.num_scalar_prefetch + i]
                 if cap.num_scalar_prefetch + i < len(cap.in_avals) else None)
-        if aval is None:
+        if aval is None or not _in_vmem(spec):
             continue
         block = _block_shape(spec, tuple(aval.shape))
         vmem += int(np.prod(block, dtype=np.int64)) * np.dtype(aval.dtype).itemsize
@@ -269,7 +278,7 @@ def check_capture(cap: PallasCapture, *, vmem_budget: int,
     for s in cap.scratch_shapes:
         shp = getattr(s, "shape", None)
         dt = getattr(s, "dtype", None)
-        if shp is not None and dt is not None:
+        if shp is not None and dt is not None and _in_vmem(s):
             vmem += int(np.prod(shp, dtype=np.int64)) * np.dtype(dt).itemsize
     if vmem > vmem_budget:
         findings.append(Finding(

@@ -309,7 +309,8 @@ def _paged_decode_attention_xla(q, k_pages, v_pages, page_table, kv_mask,
 
 @register_impl("paged_decode_attention", "pallas")
 def _paged_decode_attention_pallas(q, k_pages, v_pages, page_table, kv_mask,
-                                   *, pages_per_block: int = 1, **_):
+                                   *, pages_per_block: Optional[int] = None,
+                                   **_):
     from repro.kernels.ops import paged_decode_attention
     return paged_decode_attention(q, k_pages, v_pages, page_table, kv_mask,
                                   pages_per_block=pages_per_block)

@@ -13,6 +13,7 @@ pure-XLA model paths per op.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -66,7 +67,8 @@ def decode_attention(q, k_cache, v_cache, kv_mask, *,
 
 @functools.partial(jax.jit, static_argnames=("pages_per_block",))
 def paged_decode_attention(q, k_pages, v_pages, page_table, kv_mask, *,
-                           pages_per_block: int = 1) -> jax.Array:
+                           pages_per_block: Optional[int] = None
+                           ) -> jax.Array:
     return paged_decode_attention_splitkv(q, k_pages, v_pages, page_table,
                                           kv_mask,
                                           pages_per_block=pages_per_block,

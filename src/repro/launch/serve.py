@@ -41,9 +41,24 @@ def serve_dtype() -> str:
     return "bfloat16" if jax.default_backend() == "tpu" else "float32"
 
 
+def serving_kernels(sharded: bool = False) -> KernelPolicy:
+    """The serving default: on a TPU, with the page pool on one device,
+    paged decode attention runs the live-page Pallas kernel; every other
+    op stays XLA, as does everything on the CPU and under a mesh (a
+    ``pallas_call`` has no partitioning rule)."""
+    if jax.default_backend() == "tpu" and not sharded:
+        return KernelPolicy(paged_decode_attention="pallas")
+    return KernelPolicy.xla()
+
+
 def serving_runtime(dtype: str, kv_dtype: Optional[str] = None,
-                    kernels: Optional[KernelPolicy] = None
-                    ) -> ModelRuntime:
+                    kernels: Optional[KernelPolicy] = None,
+                    mesh=None) -> ModelRuntime:
+    """The launcher's runtime; ``kernels`` given wins over
+    :func:`serving_kernels`, which sees whether a ``mesh`` shards the
+    engine."""
+    if kernels is None:
+        kernels = serving_kernels(sharded=mesh is not None)
     return ModelRuntime(dtype=dtype, remat="none", attn_chunk=128,
                         moe_dropless=True, kv_dtype=kv_dtype,
                         kernels=kernels)
@@ -227,13 +242,13 @@ def main():
                 f"the {cap.hbm_bytes / 2**30:.1f} GiB budget — shrink "
                 f"--slots/--max-len, page the cache, or shard wider")
 
-    rt = serving_runtime(dtype, kv_dtype=args.kv_dtype)
     params = init_serving_params(cfg, args.seed, dtype)
     mesh = None
     if args.mesh:
         from repro.launch.mesh import make_mesh
         d, m = (int(x) for x in args.mesh.split("x"))
         mesh = make_mesh((d, m), ("data", "model"))
+    rt = serving_runtime(dtype, kv_dtype=args.kv_dtype, mesh=mesh)
     eng = build_engine(
         params, cfg, rt, n_slots=args.slots, max_len=args.max_len,
         buckets=buckets, admit_width=args.admit_width,

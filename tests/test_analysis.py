@@ -7,6 +7,7 @@ dispatch registration hook rejects a broken kernel with the finding
 message before it can corrupt anything at runtime.
 """
 import json
+import math
 import os
 import subprocess
 import sys
@@ -26,7 +27,7 @@ from repro.analysis.contracts import (check_axis_resolvable,
 from repro.analysis.findings import apply_suppressions, parse_suppressions
 from repro.analysis.jaxpr_lint import predict_prefill_compiles, scan_jaxpr
 from repro.analysis.kernel_validator import (capture_pallas_calls,
-                                             declares_accumulation,
+                                             check_capture,
                                              validate_impl)
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -276,7 +277,9 @@ def test_accumulation_exemptions():
 
 def test_capture_records_live_kernels():
     """The spy sees through the jitted ops wrappers and normalizes the
-    PrefetchScalarGridSpec form (paged attention's scalar page table)."""
+    PrefetchScalarGridSpec form (paged attention's scalar page table,
+    row-mask words and live-page counts), and the static checks read
+    the pool left in HBM and the kernel's DMA scratch."""
     import functools
 
     from repro.kernels.dispatch import implementations
@@ -291,10 +294,19 @@ def test_capture_records_live_kernels():
                        q, kp, kp, pt, mk)
     assert len(caps) == 1
     cap = caps[0]
-    assert cap.num_scalar_prefetch == 2     # page table + row-mask words
-    assert len(cap.grid) == 3
-    # no scratch — the race exemption comes from the output-ref reads
-    assert not cap.scratch_shapes and declares_accumulation(cap)
+    assert cap.num_scalar_prefetch == 3     # table, mask words, live pages
+    assert cap.grid == (2,)                 # one slot per grid step
+    # the pools stay in HBM; two pages a block, double-buffered in VMEM
+    assert [getattr(s.memory_space, "value", None)
+            for s in cap.in_specs[1:]] == ["any", "any"]
+    kbuf, vbuf, sems, buf = cap.scratch_shapes
+    assert kbuf.shape == vbuf.shape == (2, 2, 8, 2, 32)
+    assert sems.shape == (2, 2) and buf.shape == (1,)
+    assert check_capture(cap, vmem_budget=16 * 2**20, label="t") == []
+    # a budget the two float32 page buffers alone fill is still refused
+    page_bufs = 2 * 4 * math.prod(kbuf.shape)
+    assert rule_ids(check_capture(cap, vmem_budget=page_bufs,
+                                  label="t")) == ["kernel-vmem-budget"]
 
 
 # ======================================================================

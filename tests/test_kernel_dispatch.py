@@ -56,6 +56,24 @@ def test_explicit_policy_overrides_flag():
     assert rt.kernel_policy().impl_for("prefill_attention") == "xla"
 
 
+@pytest.mark.parametrize("backend,mesh,given,want", [
+    ("tpu", None, None, KernelPolicy(paged_decode_attention="pallas")),
+    ("cpu", None, None, XLA_POLICY),
+    ("tpu", "mesh", None, XLA_POLICY),
+    ("tpu", None, XLA_POLICY, XLA_POLICY),
+    ("cpu", None, PALLAS_POLICY, PALLAS_POLICY),
+])
+def test_serving_runtime_default_policy(monkeypatch, backend, mesh, given,
+                                        want):
+    """On a TPU with the pool on one device the launcher serves paged
+    decode attention with the Pallas kernel and every other op with XLA;
+    the CPU and a mesh stay all-XLA; a policy given wins."""
+    from repro.launch.serve import serving_runtime
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    rt = serving_runtime("float32", kernels=given, mesh=mesh)
+    assert rt.kernel_policy() == want
+
+
 def test_policy_params_merge_and_hash():
     pol = PALLAS_POLICY.with_params("prefill_attention", block_q=32)
     assert pol.params_for("prefill_attention") == {"block_q": 32}

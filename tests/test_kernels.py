@@ -84,6 +84,77 @@ def test_decode_attention(B, W, Hq, Hkv, D, bk, dtype):
         atol=_tol(dtype), rtol=_tol(dtype))
 
 
+# ---------------------------------------------------------- paged decode
+def _ring_mask(B, n_rows, width, end):
+    """Rows valid over a window of ``width`` ending at row ``end`` of a
+    ring of ``n_rows``: it wraps, so a slot's first and last pages are
+    live and the pages between its two ends are not."""
+    r = np.arange(n_rows)[None]
+    return (end[:, None] - r) % n_rows < width
+
+
+def _prefix_mask(lens, n_rows):
+    return np.arange(n_rows)[None] < np.asarray(lens)[:, None]
+
+
+# (Hq, Hkv, D, page_size, table pages, pages per block, mask)
+PAGED_CASES = {
+    # GQA (starcoder2-3b heads): 1 row, a page boundary, the full table,
+    # an empty slot; 8 live pages over blocks of 3
+    "gqa-ragged": (24, 2, 128, 16, 8, 3, ("prefix", [1, 16, 128, 0])),
+    # MHA (minicpm-2b heads, fewer of them), the same lengths
+    "mha-ragged": (4, 4, 64, 8, 8, 3, ("prefix", [1, 8, 64, 0])),
+    # pages per block from the page's bytes
+    "gqa-derived-block": (24, 2, 128, 16, 8, None, ("prefix", [37, 100])),
+    "mha-derived-block": (4, 4, 64, 8, 8, None, ("prefix", [37, 61])),
+    # windowed ring: masked pages between live ones
+    "gqa-ring": (8, 2, 32, 8, 8, 2, ("ring", 20, [5, 60])),
+    "mha-ring": (3, 3, 16, 8, 8, 3, ("ring", 12, [3, 40])),
+}
+
+
+@pytest.mark.parametrize("case", sorted(PAGED_CASES))
+def test_paged_decode_attention_matches_xla(case):
+    """The live-page kernel against the XLA gather, in float32, through
+    a shuffled page table; an empty slot's output is only finite."""
+    from repro.kernels.paged_attention import paged_decode_attention_splitkv
+    from repro.models.attention import paged_decode_attention
+
+    Hq, Hkv, D, ps, NP, pb, (kind, *how) = PAGED_CASES[case]
+    if kind == "prefix":
+        lens = how[0]
+        B = len(lens)
+        mask = _prefix_mask(lens, NP * ps)
+    else:
+        width, ends = how
+        B = len(ends)
+        mask = _ring_mask(B, NP * ps, width, np.asarray(ends))
+    P = B * NP + 1
+    ks = jax.random.split(KEY, 3)
+    q = jax.random.normal(ks[0], (B, Hq, D), jnp.float32)
+    kp = jax.random.normal(ks[1], (P, ps, Hkv, D), jnp.float32)
+    vp = jax.random.normal(ks[2], (P, ps, Hkv, D), jnp.float32)
+    pages = np.random.default_rng(0).permutation(np.arange(1, P))
+    pt = jnp.asarray(pages.reshape(B, NP), jnp.int32)
+    out = np.asarray(paged_decode_attention_splitkv(
+        q, kp, vp, pt, mask, pages_per_block=pb))
+    want = np.asarray(paged_decode_attention(q, kp, vp, pt, mask))
+    live = mask.any(axis=1)
+    np.testing.assert_allclose(out[live], want[live], atol=1e-5, rtol=1e-5)
+    assert np.isfinite(out).all()
+
+
+@pytest.mark.parametrize("mask,want", [
+    (_prefix_mask([1, 8, 9, 32], 32), [1, 1, 2, 4]),
+    (_ring_mask(2, 32, 6, np.array([2, 20])), [4, 3]),
+    (np.zeros((2, 32), bool), [1, 1]),
+])
+def test_live_pages_from_mask(mask, want):
+    """One past the last page holding a valid row, at least 1."""
+    from repro.kernels.paged_attention import live_pages
+    assert np.asarray(live_pages(mask, 8)).tolist() == want
+
+
 # ---------------------------------------------------------------- ssd
 @pytest.mark.parametrize("b,S,nh,hp,N,chunk", [
     (2, 64, 4, 16, 8, 16),

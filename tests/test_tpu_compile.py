@@ -69,6 +69,18 @@ def _kernel_case(name):
              ((B, NP), i32), ((B, NP * PAGE), "bool")]
     cases = {
         "paged_decode_attention": (ops.paged_decode_attention, paged, {}),
+        # the benchmark cells' pools: starcoder2-3b.code (GQA, 16 slots)
+        # and minicpm-2b.longdoc (MHA, 2 slots), 16-token pages, 4096 max
+        "paged_decode_attention-starcoder2-3b": (
+            ops.paged_decode_attention,
+            [((16, 24, 128), bf), ((4097, PAGE, 2, 128), bf),
+             ((4097, PAGE, 2, 128), bf), ((16, 256), i32),
+             ((16, 256 * PAGE), "bool")], {}),
+        "paged_decode_attention-minicpm-2b": (
+            ops.paged_decode_attention,
+            [((2, H, D), bf), ((385, PAGE, H, D), bf),
+             ((385, PAGE, H, D), bf), ((2, 256), i32),
+             ((2, 256 * PAGE), "bool")], {}),
         "quant_paged_decode_attention": (
             ops.quant_paged_decode_attention,
             [paged[0], ((P, PAGE, H, D), i8), ((P, PAGE, H, D), i8),
@@ -106,7 +118,8 @@ def _kernel_case(name):
 
 
 @pytest.mark.parametrize("name", [
-    "paged_decode_attention", "quant_paged_decode_attention",
+    "paged_decode_attention", "paged_decode_attention-starcoder2-3b",
+    "paged_decode_attention-minicpm-2b", "quant_paged_decode_attention",
     "decode_attention", "quant_decode_attention", "flash_attention",
     "rmsnorm", "quant_matmul", "ssd_scan", "moe_gemm"])
 def test_kernel_lowers_to_mosaic_for_v5e(one_chip, name):
@@ -143,3 +156,33 @@ def test_paged_decode_step_fits_one_v5e(one_chip, policy):
              + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     assert total < HBM_BYTES, f"{total / 2**30:.2f} GiB"
     assert ("tpu_custom_call" in compiled.as_text()) == (policy == "pallas")
+
+
+def test_paged_decode_step_fits_one_v5e_by_default(one_chip):
+    """The launcher's TPU default (the live-page kernel for paged decode
+    attention, XLA elsewhere) compiles one bf16 decode step of
+    minicpm-2b over the ``.longdoc`` cell's pool — 2 slots, 385 pages
+    of 16, 4096 tokens — within the chip's HBM."""
+    from repro.launch import serve
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(jax, "default_backend", lambda: "tpu")
+        rt = serve.serving_runtime("bfloat16")
+    assert rt.kernel_policy() == KernelPolicy(
+        paged_decode_attention="pallas")
+    cfg = get_arch("minicpm-2b")
+    slots, max_len, n_pages = 2, 4096, 385
+    spec = paged_cache_spec(cfg, slots, n_pages, PAGE, max_len, "bfloat16")
+    cache = {k: jax.ShapeDtypeStruct(s, jnp.dtype(d), sharding=one_chip)
+             for k, (s, d) in spec.items()}
+    params = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        abstract_params(cfg, "bfloat16"))
+    tokens = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=one_chip)
+    step = jax.jit(lambda p, c, t: decode_step_paged(
+        p, cfg, c, t, rt, page_size=PAGE, window=max_len))
+    compiled = step.lower(params, cache, tokens).compile()
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert total < HBM_BYTES, f"{total / 2**30:.2f} GiB"
+    assert "tpu_custom_call" in compiled.as_text()
