@@ -10,6 +10,7 @@ from repro.configs.base import (
     ModelConfig,
     MoEConfig,
     SSMConfig,
+    YarnRope,
     ShapeConfig,
     shape_skip_reason,
     smoke_config,
@@ -25,6 +26,7 @@ from repro.configs.qwen2_vl_7b import CONFIG as _qwen2_vl
 from repro.configs.hubert_xlarge import CONFIG as _hubert
 from repro.configs.zamba2_2_7b import CONFIG as _zamba2
 from repro.configs.mamba2_1_3b import CONFIG as _mamba2
+from repro.configs.mellum2_12b_a2_5b import CONFIG as _mellum2
 
 ARCHS = {
     cfg.name: cfg
@@ -39,6 +41,7 @@ ARCHS = {
         _hubert,
         _zamba2,
         _mamba2,
+        _mellum2,
     )
 }
 
@@ -61,6 +64,7 @@ __all__ = [
     "ModelConfig",
     "MoEConfig",
     "SSMConfig",
+    "YarnRope",
     "ShapeConfig",
     "get_arch",
     "get_shape",

@@ -21,6 +21,31 @@ class MoEConfig:
     d_shared_expert: int = 0       # hidden dim of each shared expert
     router_aux_loss: float = 0.01
     capacity_factor: float = 1.25  # only used for dropping-capacity EP paths
+    # Expert parallelism's share: this device holds the weights of experts
+    # ``0 .. n_held - 1`` (0 = all of them). The router still scores all
+    # ``n_experts``; tokens routed elsewhere add nothing here.
+    n_held: int = 0
+
+    @property
+    def held(self) -> int:
+        """How many experts' weights this device holds."""
+        return self.n_held or self.n_experts
+
+
+@dataclass(frozen=True)
+class YarnRope:
+    """YaRN rotary scaling (Peng et al., 2023), in the form of Hugging
+    Face's ``_compute_yarn_parameters``: frequency pairs before the
+    ``beta_fast`` correction dimension keep their frequency, those past
+    the ``beta_slow`` one are divided by ``factor``, with a linear ramp
+    between; cos and sin are multiplied by ``attention_factor``. It
+    applies at every position, not only past ``original_max_position``."""
+
+    factor: float
+    original_max_position: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 1.0
 
 
 @dataclass(frozen=True)
@@ -58,6 +83,14 @@ class ModelConfig:
     partial_rotary: float = 1.0    # fraction of head dim that rotates
     mrope_sections: Tuple[int, ...] = ()   # Qwen2-VL M-RoPE splits
     qk_norm: bool = False
+    # Attention kind per layer, as one period that repeats over the depth:
+    # "window" layers attend over the last ``sliding_window`` positions,
+    # "full" layers over the whole context. () = every layer one kind
+    # (windowed when ``sliding_window`` is set).
+    layer_pattern: Tuple[str, ...] = ()
+    # YaRN on the full-attention layers (window layers keep plain RoPE
+    # at ``rope_theta``); None = plain RoPE everywhere.
+    yarn: Optional[YarnRope] = None
     # --- block flavour ------------------------------------------------------
     norm: str = "rmsnorm"          # rmsnorm | layernorm
     mlp: str = "swiglu"            # swiglu | gelu (plain 2-matmul)
@@ -91,9 +124,21 @@ class ModelConfig:
         return not self.causal
 
     @property
+    def mixed_attention(self) -> bool:
+        """Window and full attention layers side by side."""
+        return len(set(self.layer_pattern)) > 1
+
+    def layer_kind(self, i: int) -> str:
+        """"window" or "full": the attention kind of layer ``i``."""
+        if self.layer_pattern:
+            return self.layer_pattern[i % len(self.layer_pattern)]
+        return "window" if self.sliding_window else "full"
+
+    @property
     def subquadratic(self) -> bool:
         """Eligible for the long_500k cell (assignment rule)."""
-        return self.family in ("ssm", "hybrid") or self.sliding_window > 0
+        return self.family in ("ssm", "hybrid") or (
+            self.sliding_window > 0 and not self.mixed_attention)
 
     def attention_layer_indices(self) -> Tuple[int, ...]:
         """Layer indices that run an attention block."""
@@ -147,7 +192,7 @@ class ModelConfig:
             per_expert = 3 * d * m.d_expert
             router = d * m.n_experts
             shared = m.n_shared_experts * 3 * d * (m.d_shared_expert or m.d_expert)
-            n_used = m.experts_per_token if active_only else m.n_experts
+            n_used = m.experts_per_token if active_only else m.held
             total += n_attn * (attn + router + n_used * per_expert + shared)
         elif self.family not in ("ssm", "hybrid"):
             total += n_attn * (attn + dense_mlp)
@@ -223,6 +268,8 @@ def smoke_config(cfg: ModelConfig) -> ModelConfig:
     if cfg.shared_attn_period:
         kw["n_layers"] = 4
         kw["shared_attn_period"] = 2
+    if cfg.layer_pattern:
+        kw["n_layers"] = len(cfg.layer_pattern)
     if cfg.sliding_window:
         kw["sliding_window"] = 32
     if cfg.mrope_sections:

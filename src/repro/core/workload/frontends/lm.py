@@ -56,8 +56,6 @@ def lm_block_ops(
     if kind == "decode":
         q_tokens = batch                      # one new token per sequence
         kv_len = kv_len if kv_len is not None else seq
-        if cfg.sliding_window:
-            kv_len = min(kv_len, cfg.sliding_window)
     else:
         q_tokens = batch * seq
         kv_len = seq
@@ -74,7 +72,11 @@ def lm_block_ops(
     hd, nq, nkv = cfg.head_dim, cfg.n_heads, cfg.n_kv_heads
 
     for li in range(cfg.n_layers):
+        window = cfg.sliding_window if cfg.layer_kind(li) == "window" \
+            else 0
         if li in attn_layers:
+            layer_kv = min(kv_len, window) if (
+                window and kind == "decode") else kv_len
             qkv_w = (d * nq * hd + 2 * d * nkv * hd) * wbpe
             o_w = nq * hd * d * wbpe
             qkv_flops = 2 * q_tokens * d * (nq + 2 * nkv) * hd
@@ -84,13 +86,13 @@ def lm_block_ops(
                               q_tokens * (nq + 2 * nkv) * hd * bpe, li,
                               "heads", nq, weight_dtype=wdt))
             # attention scores+pv; causal halves the effective kv per query
-            eff_kv = kv_len
+            eff_kv = layer_kv
             if cfg.causal and kind != "decode":
                 eff_kv = kv_len / 2
-                if cfg.sliding_window:
-                    eff_kv = min(eff_kv, cfg.sliding_window)
+                if window:
+                    eff_kv = min(eff_kv, window)
             attn_flops = 2 * 2 * q_tokens * nq * hd * eff_kv
-            kv_bytes = batch * kv_len * nkv * hd * 2 * kv_elem
+            kv_bytes = batch * layer_kv * nkv * hd * 2 * kv_elem
             ops.append(OpInfo(f"L{li}.attn", "attention", attn_flops, 0.0,
                               q_tokens * nq * hd * bpe + kv_bytes,
                               q_tokens * nq * hd * bpe, li,

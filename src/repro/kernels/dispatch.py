@@ -21,6 +21,7 @@ Registered ops:
     rmsnorm             layers.rmsnorm / norm()        rmsnorm_pallas
     ssd_scan            ssm_block (Mamba-2 SSD)        ssd_scan_pallas
     moe_gemm            moe_ffn dropless expert GEMM   grouped_gemm_padded
+                                                       (``ragged``: lax.ragged_dot)
     ==================  =============================  ====================
 
 Gradients: the Pallas kernels here are forward-only, so every non-xla
@@ -350,6 +351,24 @@ def _moe_gemm_xla(x, w, expert_of_row, *, n_experts: int, **_):
     import jax.numpy as jnp
     del n_experts
     return jnp.einsum("td,tdf->tf", x, w[expert_of_row])
+
+
+@register_impl("moe_gemm", "ragged")
+def _moe_gemm_ragged(x, w, expert_of_row, *, n_experts: int,
+                     sorted_rows: bool = False, **_):
+    """Grouped GEMM by ``jax.lax.ragged_dot``: each expert's weights
+    meet only its own rows. ``sorted_rows`` promises rows already
+    grouped by expert in ascending order; rows whose expert is
+    ``n_experts`` or more then come last, belong to no group and come
+    out zero. Unsorted rows are sorted here and put back."""
+    import jax.numpy as jnp
+    if not sorted_rows:
+        order = jnp.argsort(expert_of_row, stable=True)
+        y = _moe_gemm_ragged(x[order], w, expert_of_row[order],
+                             n_experts=n_experts, sorted_rows=True)
+        return y[jnp.argsort(order)]
+    sizes = jnp.bincount(expert_of_row, length=n_experts)
+    return jax.lax.ragged_dot(x, w, sizes.astype(jnp.int32))
 
 
 @register_impl("moe_gemm", "pallas")

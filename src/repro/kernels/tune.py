@@ -128,6 +128,7 @@ CI = TunePreset(
             "xla": ({},),
             "pallas": ({"block_m": 16, "block_f": 32},
                        {"block_m": 32, "block_f": 32}),
+            "ragged": ({},),
         },
         "quant_matmul": {
             "xla": ({},),
@@ -195,6 +196,7 @@ FULL = TunePreset(
             "xla": ({},),
             "pallas": ({"block_m": 128, "block_f": 512},
                        {"block_m": 256, "block_f": 512}),
+            "ragged": ({},),
         },
         "quant_matmul": {
             "xla": ({},),

@@ -43,11 +43,14 @@ def serve_dtype() -> str:
 
 def serving_kernels(sharded: bool = False) -> KernelPolicy:
     """The serving default: on a TPU, with the page pool on one device,
-    paged decode attention runs the live-page Pallas kernel; every other
-    op stays XLA, as does everything on the CPU and under a mesh (a
-    ``pallas_call`` has no partitioning rule)."""
+    paged decode attention runs the live-page Pallas kernel and an
+    expert layer its held experts' rows as grouped GEMMs
+    (``moe_gemm='ragged'``); every other op stays XLA, as does
+    everything on the CPU and under a mesh (a ``pallas_call`` has no
+    partitioning rule)."""
     if jax.default_backend() == "tpu" and not sharded:
-        return KernelPolicy(paged_decode_attention="pallas")
+        return KernelPolicy(paged_decode_attention="pallas",
+                            moe_gemm="ragged")
     return KernelPolicy.xla()
 
 

@@ -16,7 +16,7 @@ from typing import Dict, Optional, Tuple, Union
 import jax
 import jax.numpy as jnp
 
-from repro.configs.base import ModelConfig
+from repro.configs.base import ModelConfig, YarnRope
 from repro.kernels.dispatch import RMSNORM_EPS, KernelPolicy, dispatch
 
 
@@ -135,16 +135,45 @@ def rotary_dims(cfg: ModelConfig) -> int:
     return rot - (rot % 2)
 
 
+def yarn_inv_freq(rot: int, theta: float, yarn: YarnRope) -> jax.Array:
+    """YaRN's (rot/2,) frequencies, as Hugging Face's
+    ``_compute_yarn_parameters`` computes them (correction range
+    truncated to whole dimensions)."""
+    def corr_dim(rotations: float) -> float:
+        return (rot * math.log(yarn.original_max_position
+                               / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(corr_dim(yarn.beta_fast)), 0)
+    high = min(math.ceil(corr_dim(yarn.beta_slow)), rot - 1)
+    if low == high:
+        high += 0.001
+    half = rot // 2
+    extrap = 1.0 / theta ** (jnp.arange(0, rot, 2, dtype=jnp.float32) / rot)
+    ramp = jnp.clip((jnp.arange(half, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    # ramp 0: keep the frequency; ramp 1: divide it by the factor
+    return extrap / yarn.factor * ramp + extrap * (1.0 - ramp)
+
+
 def _rope_cos_sin(positions: jax.Array, rot: int, theta: float,
-                  sections: Tuple[int, ...] = ()) -> Tuple[jax.Array, jax.Array]:
+                  sections: Tuple[int, ...] = (),
+                  yarn: Optional[YarnRope] = None,
+                  ) -> Tuple[jax.Array, jax.Array]:
     """cos/sin tables (..., rot/2).
 
     positions: (B, S) for standard RoPE, or (3, B, S) for M-RoPE where
     the leading axis is (temporal, height, width) and ``sections`` gives
-    the number of frequency *pairs* assigned to each component.
+    the number of frequency *pairs* assigned to each component. With
+    ``yarn`` the frequencies are YaRN's and both tables are scaled by its
+    attention factor.
     """
     half = rot // 2
-    inv_freq = 1.0 / (theta ** (jnp.arange(0, half, dtype=jnp.float32) / half))
+    if yarn is not None:
+        inv_freq = yarn_inv_freq(rot, theta, yarn)
+    else:
+        inv_freq = 1.0 / (theta ** (jnp.arange(0, half, dtype=jnp.float32)
+                                    / half))
     if sections:
         assert positions.ndim == 3, "M-RoPE needs (3, B, S) positions"
         assert sum(sections) == half, (sections, half)
@@ -160,17 +189,26 @@ def _rope_cos_sin(positions: jax.Array, rot: int, theta: float,
         if positions.ndim == 3:      # text fed to an M-RoPE-less model
             positions = positions[0]
         freqs = positions[..., None].astype(jnp.float32) * inv_freq
+    if yarn is not None:
+        a = yarn.attention_factor
+        return jnp.cos(freqs) * a, jnp.sin(freqs) * a
     return jnp.cos(freqs), jnp.sin(freqs)
 
 
 def apply_rope(q: jax.Array, k: jax.Array, positions: jax.Array,
-               cfg: ModelConfig) -> Tuple[jax.Array, jax.Array]:
-    """q: (B, S, Hq, hd), k: (B, S, Hkv, hd); positions (B,S) or (3,B,S)."""
+               cfg: ModelConfig, kind: Optional[str] = None,
+               ) -> Tuple[jax.Array, jax.Array]:
+    """q: (B, S, Hq, hd), k: (B, S, Hkv, hd); positions (B,S) or (3,B,S).
+
+    ``kind`` is the layer's attention kind: ``cfg.yarn`` scales every
+    layer but the "window" ones."""
     if cfg.rope == "none":
         return q, k
     rot = rotary_dims(cfg)
+    yarn = cfg.yarn if kind != "window" else None
     cos, sin = _rope_cos_sin(positions, rot, cfg.rope_theta,
-                             cfg.mrope_sections if cfg.rope == "mrope" else ())
+                             cfg.mrope_sections if cfg.rope == "mrope" else (),
+                             yarn=yarn)
     cos = cos[:, :, None, :]      # (B, S, 1, rot/2)
     sin = sin[:, :, None, :]
 

@@ -16,7 +16,9 @@ and the 512-chip dry-run.
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, List, Optional, Tuple
+
+import numpy as np
 
 import jax
 import jax.numpy as jnp
@@ -163,21 +165,32 @@ def _attn_proj(p, h, cfg, policy=None):
     return q, k, v
 
 
+def _kind_window(cfg: ModelConfig, kind: Optional[str]) -> int:
+    """Attention window of a layer of ``kind`` (0 = full); ``None`` is a
+    model of one kind, windowed when ``sliding_window`` is set."""
+    if kind == "full":
+        return 0
+    return cfg.sliding_window
+
+
 def attn_block(p: Dict[str, Any], x: jax.Array, positions: jax.Array,
                cfg: ModelConfig, rt: ModelRuntime,
+               kind: Optional[str] = None,
                ) -> Tuple[jax.Array, jax.Array, Tuple[jax.Array, jax.Array]]:
     """Pre-norm attention + FFN block. Returns (x, aux_loss, (k, v)).
 
     k/v are post-RoPE — exactly what the decode cache stores; callers
-    that don't prefill simply drop them (XLA dead-code-eliminates)."""
+    that don't prefill simply drop them (XLA dead-code-eliminates).
+    ``kind`` ("window" / "full") is the layer's attention kind in a model
+    of mixed kinds."""
     pol = rt.kernel_policy()
     h = norm(x, p["ln1"], cfg.norm, policy=pol)
     q, k, v = _attn_proj(p, h, cfg, policy=pol)
-    q, k = L.apply_rope(q, k, positions, cfg)
+    q, k = L.apply_rope(q, k, positions, cfg, kind)
     q = constrain(q, ("batch", "seq", "heads", "head_dim"))
     k = constrain(k, ("batch", "seq", "kv_heads", "head_dim"))
     o = dispatch("prefill_attention", pol, q, k, v, causal=cfg.causal,
-                 window=cfg.sliding_window, chunk=rt.attn_chunk)
+                 window=_kind_window(cfg, kind), chunk=rt.attn_chunk)
     o = o.reshape(x.shape[0], x.shape[1], -1)
     x = x + o @ p["wo"].astype(x.dtype)
     x = constrain(x, ("batch", "seq", "embed"))
@@ -185,8 +198,8 @@ def attn_block(p: Dict[str, Any], x: jax.Array, positions: jax.Array,
     h2 = norm(x, p["ln2"], cfg.norm, policy=pol)
     aux = jnp.zeros((), jnp.float32)
     if cfg.moe is not None:
-        y, aux = MOE.moe_ffn(p["moe"], h2, cfg, dropless=rt.moe_dropless,
-                             token_chunk=rt.moe_chunk, policy=pol)
+        y, aux, _ = MOE.moe_ffn(p["moe"], h2, cfg, dropless=rt.moe_dropless,
+                                token_chunk=rt.moe_chunk, policy=pol)
     else:
         y = _mlp(p, h2, cfg)
     x = x + y
@@ -265,6 +278,8 @@ def _scan_blocks(params, cfg: ModelConfig, x, positions, rt: ModelRuntime):
         return x, aux, states
     if fam == "hybrid":
         return _hybrid_scan(params, cfg, x, positions, rt)
+    if cfg.mixed_attention:
+        return _period_scan(params, cfg, x, positions, rt)
 
     def body_fn(xp, xs):
         return attn_block(xs, xp, positions, cfg, rt)
@@ -279,6 +294,46 @@ def _scan_blocks(params, cfg: ModelConfig, x, positions, rt: ModelRuntime):
     (x, aux), kvs = jax.lax.scan(body_scan, (x, zero), params["blocks"],
                                  unroll=rt.unroll_layers)
     return x, aux, kvs
+
+
+def _periods(cfg: ModelConfig, tree, n: Optional[int] = None):
+    """Layer-stacked leaves ``(n_layers, ...)`` -> ``(n_periods, n, ...)``
+    (``n`` layers of each period; default the whole period)."""
+    P = len(cfg.layer_pattern)
+    if cfg.n_layers % P:
+        raise ValueError(f"{cfg.name}: {cfg.n_layers} layers are not whole "
+                         f"periods of {cfg.layer_pattern}")
+    n = P if n is None else n
+    return jax.tree.map(
+        lambda a: a.reshape((cfg.n_layers // P, n) + a.shape[1:]), tree)
+
+
+def _period_scan(params, cfg: ModelConfig, x, positions, rt):
+    """Mixed attention kinds: scan over periods of ``layer_pattern``, the
+    layers of a period unrolled, each with its own window and rope."""
+    zero = jnp.zeros((), jnp.float32)
+
+    def period_fn(xp, lp):
+        aux, ks, vs = zero, [], []
+        for j, kind in enumerate(cfg.layer_pattern):
+            pj = jax.tree.map(lambda a: a[j], lp)
+            xp, a, (k, v) = attn_block(pj, xp, positions, cfg, rt, kind)
+            aux, ks, vs = aux + a, ks + [k], vs + [v]
+        return xp, aux, (jnp.stack(ks), jnp.stack(vs))
+
+    body = _maybe_remat(period_fn, rt)
+
+    def body_scan(carry, lp):
+        x_, aux_ = carry
+        x2, a, kv = body(x_, lp)
+        return (x2, aux_ + a), kv
+
+    (x, aux), (k, v) = jax.lax.scan(body_scan, (x, zero),
+                                    _periods(cfg, params["blocks"]),
+                                    unroll=rt.unroll_layers)
+    L_ = cfg.n_layers
+    return x, aux, (k.reshape((L_,) + k.shape[2:]),
+                    v.reshape((L_,) + v.shape[2:]))
 
 
 def _hybrid_scan(params, cfg: ModelConfig, x, positions, rt):
@@ -371,6 +426,42 @@ def _fill_kv_window(k_full: jax.Array, W: int) -> jax.Array:
     return out.at[:, idx].set(k_full[:, -W:])
 
 
+def _fill_kv_ring(rows: jax.Array, W: int, lengths: jax.Array) -> jax.Array:
+    """Place (B, S, Hkv, hd) prefill rows into a W-row ring by each row's
+    *real* length: ring row r holds the latest position p < length with
+    p % W == r, so the ring ends at the last real token even when pad
+    tokens follow it; rows no real position reaches hold rows the decode
+    mask hides until they are written."""
+    S = rows.shape[1]
+    last = lengths[:, None] - 1
+    p = last - jnp.mod(last - jnp.arange(W)[None, :], W)       # (B, W)
+    p = jnp.clip(p, 0, S - 1)
+    return jnp.take_along_axis(rows, p[:, :, None, None], axis=1)
+
+
+def layers_of(cfg: ModelConfig, kind: str) -> List[int]:
+    """Indices of the layers of attention ``kind``, in order."""
+    return [i for i in range(cfg.n_layers) if cfg.layer_kind(i) == kind]
+
+
+def _mixed_kv_leaves(kvs, cfg: ModelConfig, max_len: int, lengths,
+                     rt: ModelRuntime) -> Dict[str, jax.Array]:
+    """The two cache groups of a model of mixed kinds from the prefill's
+    per-layer (k, v): full layers ``k``/``v`` at ``max_len`` rows, window
+    layers ``kw``/``vw`` in their ring of ``_ring_window`` rows."""
+    if rt.kv_dtype == "int8":
+        raise NotImplementedError("int8 KV for mixed attention kinds")
+    full = np.asarray(layers_of(cfg, "full"))
+    win = np.asarray(layers_of(cfg, "window"))
+    W = _ring_window(cfg, max_len)
+    kvd = rt.kv_dtype or rt.dtype
+    k, v = kvs
+    fill = jax.vmap(lambda t: _fill_kv_window(t, max_len))
+    ring = jax.vmap(lambda t: _fill_kv_ring(t, W, lengths))
+    return {"k": fill(k[full]).astype(kvd), "v": fill(v[full]).astype(kvd),
+            "kw": ring(k[win]).astype(kvd), "vw": ring(v[win]).astype(kvd)}
+
+
 def prefill(params, cfg: ModelConfig, batch: Dict[str, jax.Array],
             max_len: int, rt: ModelRuntime = ModelRuntime(),
             lengths: Optional[jax.Array] = None,
@@ -405,7 +496,10 @@ def prefill(params, cfg: ModelConfig, batch: Dict[str, jax.Array],
         pos = jnp.full((B,), S, jnp.int32)
     else:
         pos = jnp.asarray(lengths, jnp.int32)
-    if fam in ("dense", "moe", "vlm", "audio"):
+    if fam in ("dense", "moe", "vlm", "audio") and cfg.mixed_attention:
+        cache = {"pos": pos, **_mixed_kv_leaves(cachemat, cfg, max_len,
+                                                pos, rt)}
+    elif fam in ("dense", "moe", "vlm", "audio"):
         kvs = cachemat                      # (k, v): (nL, B, S, Hkv, hd)
         k = jax.vmap(lambda t: _fill_kv_window(t, W))(kvs[0])
         v = jax.vmap(lambda t: _fill_kv_window(t, W))(kvs[1])
@@ -442,9 +536,16 @@ def prefill(params, cfg: ModelConfig, batch: Dict[str, jax.Array],
 # Decode (KV / state caches)
 # ===========================================================================
 def _cache_window(cfg: ModelConfig, max_len: int) -> int:
-    if cfg.sliding_window:
+    """Rows a slot's cache holds (of the full layers, in a model of mixed
+    kinds)."""
+    if cfg.sliding_window and not cfg.mixed_attention:
         return min(cfg.sliding_window, max_len)
     return max_len
+
+
+def _ring_window(cfg: ModelConfig, max_len: int) -> int:
+    """Rows of the window layers' ring in a model of mixed kinds."""
+    return min(cfg.sliding_window, max_len)
 
 
 def cache_token_budget(cfg: ModelConfig, max_len: int,
@@ -482,7 +583,15 @@ def cache_spec(cfg: ModelConfig, batch: int, max_len: int,
         "pos": ((batch,), jnp.int32),    # per-sequence positions
     }
     fam = cfg.family
-    if fam in ("dense", "moe", "vlm", "audio"):
+    if fam in ("dense", "moe", "vlm", "audio") and cfg.mixed_attention:
+        # full layers at W = max_len rows, window layers in their ring
+        nf, nw = len(layers_of(cfg, "full")), len(layers_of(cfg, "window"))
+        Wr = _ring_window(cfg, max_len)
+        spec["k"] = ((nf, batch, W, cfg.n_kv_heads, hd), kvd)
+        spec["v"] = ((nf, batch, W, cfg.n_kv_heads, hd), kvd)
+        spec["kw"] = ((nw, batch, Wr, cfg.n_kv_heads, hd), kvd)
+        spec["vw"] = ((nw, batch, Wr, cfg.n_kv_heads, hd), kvd)
+    elif fam in ("dense", "moe", "vlm", "audio"):
         spec["k"] = ((cfg.n_layers, batch, W, cfg.n_kv_heads, hd), kvd)
         spec["v"] = ((cfg.n_layers, batch, W, cfg.n_kv_heads, hd), kvd)
         if kvd == "int8":
@@ -508,6 +617,8 @@ CACHE_AXES = {
     "pos": ("batch",),
     "k": (None, "batch", "kv_seq", "kv_heads", None),
     "v": (None, "batch", "kv_seq", "kv_heads", None),
+    "kw": (None, "batch", "kv_seq", "kv_heads", None),
+    "vw": (None, "batch", "kv_seq", "kv_heads", None),
     "ks": (None, "batch", "kv_seq", "kv_heads"),
     "vs": (None, "batch", "kv_seq", "kv_heads"),
     "conv": (None, "batch", None, "ssm_inner"),
@@ -556,6 +667,14 @@ def paged_cache_spec(cfg: ModelConfig, n_slots: int, n_pages: int,
     slots can never corrupt pages that have been rebound to live
     requests. Recurrent state (``conv``/``ssm``) is O(1) per slot and
     stays contiguous; only the KV rows page.
+
+    A model of mixed attention kinds keeps two groups: its full layers
+    in ``kp``/``vp`` (``n_pages``) under ``pt``, ``ceil(max_len /
+    page_size)`` pages a slot; its window layers in ``kpw``/``vpw``, a
+    whole ring per slot and the null page, under ``ptw``, a ring of
+    ``ceil(W / page_size)`` pages. An expert
+    model adds ``expert_counts``: int32 ``[rows, experts hit]`` of the
+    last decode step, summed over layers.
     """
     hd = cfg.head_dim
     W = _cache_window(cfg, max_len)
@@ -566,7 +685,21 @@ def paged_cache_spec(cfg: ModelConfig, n_slots: int, n_pages: int,
         "pt": ((n_slots, npp), jnp.int32),
     }
     fam = cfg.family
-    if fam in ("dense", "moe", "vlm", "audio"):
+    if cfg.moe is not None:
+        spec["expert_counts"] = ((2,), jnp.int32)
+    if fam in ("dense", "moe", "vlm", "audio") and cfg.mixed_attention:
+        if kvd == "int8":
+            raise NotImplementedError("int8 KV for mixed attention kinds")
+        npw = page_count(_ring_window(cfg, max_len), page_size)
+        n_ring_pages = n_slots * npw + 1
+        row = (page_size, cfg.n_kv_heads, hd)
+        nf, nw = len(layers_of(cfg, "full")), len(layers_of(cfg, "window"))
+        spec["ptw"] = ((n_slots, npw), jnp.int32)
+        spec["kp"] = ((nf, n_pages) + row, kvd)
+        spec["vp"] = ((nf, n_pages) + row, kvd)
+        spec["kpw"] = ((nw, n_ring_pages) + row, kvd)
+        spec["vpw"] = ((nw, n_ring_pages) + row, kvd)
+    elif fam in ("dense", "moe", "vlm", "audio"):
         shape = (cfg.n_layers, n_pages, page_size, cfg.n_kv_heads, hd)
         spec["kp"] = (shape, kvd)
         spec["vp"] = (shape, kvd)
@@ -595,8 +728,12 @@ def paged_cache_spec(cfg: ModelConfig, n_slots: int, n_pages: int,
 PAGED_CACHE_AXES = {
     "pos": ("batch",),
     "pt": ("batch", None),
+    "ptw": ("batch", None),
     "kp": (None, None, None, "kv_heads", None),
     "vp": (None, None, None, "kv_heads", None),
+    "kpw": (None, None, None, "kv_heads", None),
+    "vpw": (None, None, None, "kv_heads", None),
+    "expert_counts": (None,),
     "ks": (None, None, None, "kv_heads"),
     "vs": (None, None, None, "kv_heads"),
     "conv": CACHE_AXES["conv"],
@@ -659,13 +796,37 @@ def write_prefill_pages_quant(kp, vp, ks_pool, vs_pool, k, v, ks, vs,
     return kp, vp, ks_pool, vs_pool
 
 
+def _ffn_decode(p, h2: jax.Array, cfg: ModelConfig, pol: KernelPolicy,
+                moe_layer: Optional[jax.Array] = None):
+    """The block's FFN on one token a row: (B, d) -> (y, expert counts,
+    or None for a model without experts). With ``moe_layer``,
+    ``p["moe"]`` is every layer's, stacked (``moe.moe_ffn``'s
+    ``layer``)."""
+    if cfg.moe is not None:
+        y, _, counts = MOE.moe_ffn(p["moe"], h2[:, None, :], cfg,
+                                   dropless=True, policy=pol,
+                                   layer=moe_layer)
+        return y[:, 0], counts
+    return _mlp(p, h2[:, None, :], cfg)[:, 0], None
+
+
+def _add_counts(a: Optional[jax.Array], b: Optional[jax.Array]):
+    return None if b is None else a + b
+
+
 def _attn_decode_one_paged(p, x, kp, vp, pt, pos, window: int,
                            page_size: int, cfg: ModelConfig,
-                           rt: ModelRuntime):
+                           rt: ModelRuntime, kind: Optional[str] = None,
+                           layer: Optional[jax.Array] = None,
+                           moe_layer: Optional[jax.Array] = None):
     """One-layer paged attention for one token. The new K/V row is
     written *through the page table* at physical page
     ``pt[b, (pos % W) // ps]``, then attention gathers every owned page
-    via the ``paged_decode_attention`` dispatch op."""
+    via the ``paged_decode_attention`` dispatch op. With ``layer``,
+    ``kp``/``vp`` are a group's stacked pools: the row is written in
+    place into that layer's, and attention reads that layer's pages
+    through the page table offset into the stack; ``moe_layer`` is
+    :func:`_ffn_decode`'s. Returns (x, kp, vp, expert counts or None)."""
     B = x.shape[0]
     W, ps = window, page_size
     pol = rt.kernel_policy()
@@ -674,26 +835,33 @@ def _attn_decode_one_paged(p, x, kp, vp, pt, pos, window: int,
     posv = pos[:, None]                                  # (B, 1)
     if cfg.rope == "mrope":
         posv = jnp.broadcast_to(posv[None], (3, B, 1))
-    q, k = L.apply_rope(q, k, posv, cfg)
+    q, k = L.apply_rope(q, k, posv, cfg, kind)
     row = (pos % W).astype(jnp.int32)                    # (B,)
     phys = jnp.take_along_axis(pt, (row // ps)[:, None], axis=1)[:, 0]
     with jax.named_scope("kv_write"):
-        kp = kp.at[phys, row % ps].set(k[:, 0].astype(kp.dtype))
-        vp = vp.at[phys, row % ps].set(v[:, 0].astype(vp.dtype))
+        if layer is None:
+            kp = kp.at[phys, row % ps].set(k[:, 0].astype(kp.dtype))
+            vp = vp.at[phys, row % ps].set(v[:, 0].astype(vp.dtype))
+        else:
+            kp = kp.at[layer, phys, row % ps].set(k[:, 0].astype(kp.dtype))
+            vp = vp.at[layer, phys, row % ps].set(v[:, 0].astype(vp.dtype))
     Wp = pt.shape[1] * ps
     ar = jnp.arange(Wp)[None, :]
     mask = (ar <= pos[:, None]) & (ar < W)               # (B, Wp)
-    o = dispatch("paged_decode_attention", pol, q[:, 0], kp, vp, pt, mask)
+    if layer is None:
+        o = dispatch("paged_decode_attention", pol, q[:, 0], kp, vp, pt,
+                     mask)
+    else:
+        # the group's pools read as one pool of all its layers' pages
+        # (a free reshape), the table offset to this layer's: no copy
+        flat = lambda a: a.reshape((-1,) + a.shape[2:])   # noqa: E731
+        o = dispatch("paged_decode_attention", pol, q[:, 0], flat(kp),
+                     flat(vp), pt + layer * kp.shape[1], mask)
     x = x + o.reshape(B, -1) @ p["wo"].astype(x.dtype)
 
     h2 = norm(x, p["ln2"], cfg.norm, policy=pol)
-    if cfg.moe is not None:
-        y, _ = MOE.moe_ffn(p["moe"], h2[:, None, :], cfg, dropless=True,
-                           policy=pol)
-        y = y[:, 0]
-    else:
-        y = _mlp(p, h2[:, None, :], cfg)[:, 0]
-    return x + y, kp, vp
+    y, counts = _ffn_decode(p, h2, cfg, pol, moe_layer)
+    return x + y, kp, vp, counts
 
 
 def _attn_decode_one_paged_q(p, x, kp, vp, ks, vs, pt, pos, window: int,
@@ -732,12 +900,7 @@ def _attn_decode_one_paged_q(p, x, kp, vp, ks, vs, pt, pos, window: int,
     x = x + o.reshape(B, -1) @ p["wo"].astype(x.dtype)
 
     h2 = norm(x, p["ln2"], cfg.norm, policy=pol)
-    if cfg.moe is not None:
-        y, _ = MOE.moe_ffn(p["moe"], h2[:, None, :], cfg, dropless=True,
-                           policy=pol)
-        y = y[:, 0]
-    else:
-        y = _mlp(p, h2[:, None, :], cfg)[:, 0]
+    y, _ = _ffn_decode(p, h2, cfg, pol)
     return x + y, kp, vp, ks, vs
 
 
@@ -752,6 +915,9 @@ def decode_step_paged(params, cfg: ModelConfig, cache: Dict[str, jax.Array],
     fam = cfg.family
     if fam == "ssm":
         return decode_step(params, cfg, cache, tokens, rt)
+    if cfg.mixed_attention:
+        return _decode_step_paged_mixed(params, cfg, cache, tokens, rt,
+                                        page_size=page_size, window=window)
     pos = cache["pos"]
     pt = cache["pt"]
     x = params["embed"].astype(rt.dtype)[tokens]          # (B, d)
@@ -774,16 +940,20 @@ def decode_step_paged(params, cfg: ModelConfig, cache: Dict[str, jax.Array],
             new_cache = dict(cache, pos=pos + 1, kp=kp_new, vp=vp_new,
                              ks=ks_new, vs=vs_new)
         else:
-            def body(x_, xs):
+            def body(carry, xs):
+                x_, n_ = carry
                 lp, kp, vp = xs
-                x2, kp, vp = _attn_decode_one_paged(
+                x2, kp, vp, n2 = _attn_decode_one_paged(
                     lp, x_, kp, vp, pt, pos, window, page_size, cfg, rt)
-                return x2, (kp, vp)
+                return (x2, _add_counts(n_, n2)), (kp, vp)
 
-            x, (kp_new, vp_new) = jax.lax.scan(
-                body, x, (params["blocks"], cache["kp"], cache["vp"]),
+            (x, counts), (kp_new, vp_new) = jax.lax.scan(
+                body, (x, _zero_counts(cfg)),
+                (params["blocks"], cache["kp"], cache["vp"]),
                 unroll=rt.unroll_layers)
             new_cache = dict(cache, pos=pos + 1, kp=kp_new, vp=vp_new)
+            if counts is not None:
+                new_cache["expert_counts"] = counts
     else:  # hybrid
         period = cfg.shared_attn_period
         n_groups = cfg.n_layers // period
@@ -833,7 +1003,7 @@ def decode_step_paged(params, cfg: ModelConfig, cache: Dict[str, jax.Array],
                 gp, gidx, convs, ssms, kp, vp = xs
                 x_, (conv2, ssm2) = jax.lax.scan(
                     inner, x_, (gp, convs, ssms), unroll=rt.unroll_layers)
-                x_, kp, vp = _attn_decode_one_paged(
+                x_, kp, vp, _ = _attn_decode_one_paged(
                     _shared_block(gidx), x_, kp, vp, pt, pos, window,
                     page_size, cfg, rt)
                 return x_, (conv2, ssm2, kp, vp)
@@ -853,10 +1023,70 @@ def decode_step_paged(params, cfg: ModelConfig, cache: Dict[str, jax.Array],
     return new_cache, logits
 
 
+def _zero_counts(cfg: ModelConfig) -> Optional[jax.Array]:
+    return jnp.zeros((2,), jnp.int32) if cfg.moe is not None else None
+
+
+def _decode_step_paged_mixed(params, cfg: ModelConfig, cache, tokens, rt,
+                             *, page_size: int, window: int):
+    """:func:`decode_step_paged` for a model of mixed attention kinds:
+    a scan over periods of ``layer_pattern``, the layers of a period
+    unrolled. Window layers write and read their ring (``kpw``/``vpw``
+    under ``ptw``, ``_ring_window`` rows), full layers the full-length
+    group (``kp``/``vp`` under ``pt``, ``window`` rows). The pools ride
+    the scan's carry and take each new row in place; expert weights stay
+    stacked (their grouped GEMMs read each layer's in place)."""
+    pos = cache["pos"]
+    ring = _ring_window(cfg, window)
+    x = params["embed"].astype(rt.dtype)[tokens]          # (B, d)
+    pattern = cfg.layer_pattern
+    nw = pattern.count("window")
+    nf = len(pattern) - nw
+    blocks = dict(params["blocks"])
+    experts = blocks.pop("moe", None)
+
+    def body(carry, xs):
+        x_, n_, kw, vw, kf, vf = carry
+        lp, period = xs
+        iw = jf = 0
+        for j, kind in enumerate(pattern):
+            pj = jax.tree.map(lambda a: a[j], lp)
+            moe_layer = None
+            if experts is not None:
+                pj["moe"], moe_layer = experts, period * len(pattern) + j
+            if kind == "window":
+                x_, kw, vw, n2 = _attn_decode_one_paged(
+                    pj, x_, kw, vw, cache["ptw"], pos, ring, page_size, cfg,
+                    rt, kind, layer=period * nw + iw, moe_layer=moe_layer)
+                iw += 1
+            else:
+                x_, kf, vf, n2 = _attn_decode_one_paged(
+                    pj, x_, kf, vf, cache["pt"], pos, window, page_size, cfg,
+                    rt, kind, layer=period * nf + jf, moe_layer=moe_layer)
+                jf += 1
+            n_ = _add_counts(n_, n2)
+        return (x_, n_, kw, vw, kf, vf), None
+
+    n_periods = cfg.n_layers // len(pattern)
+    (x, counts, kw, vw, kf, vf), _ = jax.lax.scan(
+        body, (x, _zero_counts(cfg), cache["kpw"], cache["vpw"], cache["kp"],
+               cache["vp"]),
+        (_periods(cfg, blocks), jnp.arange(n_periods)),
+        unroll=rt.unroll_layers)
+    new_cache = dict(cache, pos=pos + 1, kpw=kw, vpw=vw, kp=kf, vp=vf)
+    if counts is not None:
+        new_cache["expert_counts"] = counts
+    pol = rt.kernel_policy()
+    x = norm(x[:, None, :], params["final_norm"], cfg.norm, policy=pol)
+    return new_cache, _unembed(params, cfg, x)[:, 0]
+
+
 def _attn_decode_one(p, x, k_cache, v_cache, pos, cfg: ModelConfig,
-                     rt: ModelRuntime):
+                     rt: ModelRuntime, kind: Optional[str] = None):
     """One-layer attention for one token. x: (B, d); pos: (B,) int32 —
-    per-sequence positions (continuous batching)."""
+    per-sequence positions (continuous batching); ``kind`` the layer's
+    attention kind in a model of mixed kinds (its cache holds its rows:
+    a window layer's is the ring)."""
     B = x.shape[0]
     hd = cfg.head_dim
     W = k_cache.shape[1]
@@ -866,7 +1096,7 @@ def _attn_decode_one(p, x, k_cache, v_cache, pos, cfg: ModelConfig,
     posv = pos[:, None]                                  # (B, 1)
     if cfg.rope == "mrope":
         posv = jnp.broadcast_to(posv[None], (3, B, 1))
-    q, k = L.apply_rope(q, k, posv, cfg)
+    q, k = L.apply_rope(q, k, posv, cfg, kind)
     slot = (pos % W).astype(jnp.int32)                   # (B,)
     bidx = jnp.arange(B)
     k_cache = k_cache.at[bidx, slot].set(k[:, 0].astype(k_cache.dtype))
@@ -876,12 +1106,7 @@ def _attn_decode_one(p, x, k_cache, v_cache, pos, cfg: ModelConfig,
     x = x + o.reshape(B, -1) @ p["wo"].astype(x.dtype)
 
     h2 = norm(x, p["ln2"], cfg.norm, policy=pol)
-    if cfg.moe is not None:
-        y, _ = MOE.moe_ffn(p["moe"], h2[:, None, :], cfg, dropless=True,
-                           policy=pol)
-        y = y[:, 0]
-    else:
-        y = _mlp(p, h2[:, None, :], cfg)[:, 0]
+    y, _ = _ffn_decode(p, h2, cfg, pol)
     return x + y, k_cache, v_cache
 
 
@@ -916,19 +1141,53 @@ def _attn_decode_one_q(p, x, k_cache, v_cache, ks_cache, vs_cache, pos,
     x = x + o.reshape(B, -1) @ p["wo"].astype(x.dtype)
 
     h2 = norm(x, p["ln2"], cfg.norm, policy=pol)
-    if cfg.moe is not None:
-        y, _ = MOE.moe_ffn(p["moe"], h2[:, None, :], cfg, dropless=True,
-                           policy=pol)
-        y = y[:, 0]
-    else:
-        y = _mlp(p, h2[:, None, :], cfg)[:, 0]
+    y, _ = _ffn_decode(p, h2, cfg, pol)
     return x + y, k_cache, v_cache, ks_cache, vs_cache
+
+
+def _decode_step_mixed(params, cfg: ModelConfig, cache, tokens, rt):
+    """:func:`decode_step` for a model of mixed attention kinds: a scan
+    over periods of ``layer_pattern``, the layers of a period unrolled;
+    window layers in their ring (``kw``/``vw``), full layers in ``k``/
+    ``v``."""
+    pos = cache["pos"]
+    x = params["embed"].astype(rt.dtype)[tokens]          # (B, d)
+    nw = cfg.layer_pattern.count("window")
+    nf = len(cfg.layer_pattern) - nw
+
+    def body(x_, xs):
+        lp, kw, vw, kf, vf = xs
+        new = {"window": [], "full": []}
+        for j, kind in enumerate(cfg.layer_pattern):
+            pj = jax.tree.map(lambda a: a[j], lp)
+            kc, vc = (kw, vw) if kind == "window" else (kf, vf)
+            i = len(new[kind])
+            x_, k2, v2 = _attn_decode_one(pj, x_, kc[i], vc[i], pos, cfg,
+                                          rt, kind)
+            new[kind].append((k2, v2))
+        return x_, tuple(jnp.stack([kv[i] for kv in new[kind]])
+                         for kind in ("window", "full") for i in (0, 1))
+
+    xs = (_periods(cfg, params["blocks"]),
+          _periods(cfg, cache["kw"], nw), _periods(cfg, cache["vw"], nw),
+          _periods(cfg, cache["k"], nf), _periods(cfg, cache["v"], nf))
+    x, (kw, vw, kf, vf) = jax.lax.scan(body, x, xs, unroll=rt.unroll_layers)
+    new_cache = dict(cache, pos=pos + 1,
+                     kw=kw.reshape(cache["kw"].shape),
+                     vw=vw.reshape(cache["vw"].shape),
+                     k=kf.reshape(cache["k"].shape),
+                     v=vf.reshape(cache["v"].shape))
+    pol = rt.kernel_policy()
+    x = norm(x[:, None, :], params["final_norm"], cfg.norm, policy=pol)
+    return new_cache, _unembed(params, cfg, x)[:, 0]
 
 
 def decode_step(params, cfg: ModelConfig, cache: Dict[str, jax.Array],
                 tokens: jax.Array, rt: ModelRuntime = ModelRuntime(),
                 ) -> Tuple[Dict[str, jax.Array], jax.Array]:
     """tokens: (B,) int32 -> (new cache, logits (B, V))."""
+    if cfg.mixed_attention:
+        return _decode_step_mixed(params, cfg, cache, tokens, rt)
     pos = cache["pos"]
     x = params["embed"].astype(rt.dtype)[tokens]          # (B, d)
     fam = cfg.family

@@ -1,10 +1,16 @@
 """Mixture-of-Experts layer: top-k token-choice routing with GShard-style
-capacity-bounded einsum dispatch.
+capacity-bounded einsum dispatch, or grouped GEMMs over routed rows.
 
 The dense dispatch/combine einsums shard cleanly over an ``experts``
 logical axis (expert parallelism): per-expert weights live on their
 chips and the dispatch einsum lowers to an all-to-all on the expert
 axis. Capacity-dropped tokens fall through the residual connection.
+
+A layer may hold only some experts (``MoEConfig.n_held``, expert
+parallelism's share of one device): the router still scores all
+``n_experts`` and renormalises over the top k, and the layer returns
+the part of the result that its own experts give; rows routed to
+experts it does not hold add nothing.
 """
 from __future__ import annotations
 
@@ -28,11 +34,11 @@ def moe_defs(cfg: ModelConfig, stack: Tuple[int, ...] = ()) -> Dict:
     defs = {
         "router": ParamDef(stack + (d, m.n_experts),
                            saxes + (None, "experts")),
-        "wi": ParamDef(stack + (m.n_experts, d, m.d_expert),
+        "wi": ParamDef(stack + (m.held, d, m.d_expert),
                        saxes + ("experts", "embed", "expert_ffn")),
-        "wg": ParamDef(stack + (m.n_experts, d, m.d_expert),
+        "wg": ParamDef(stack + (m.held, d, m.d_expert),
                        saxes + ("experts", "embed", "expert_ffn")),
-        "wo": ParamDef(stack + (m.n_experts, m.d_expert, d),
+        "wo": ParamDef(stack + (m.held, m.d_expert, d),
                        saxes + ("experts", "expert_ffn", "embed")),
     }
     if m.n_shared_experts:
@@ -51,8 +57,16 @@ def moe_ffn(p: Dict[str, jax.Array], x: jax.Array,
             cfg: ModelConfig, dropless: bool = False,
             token_chunk: int = 0,
             policy: Optional[KernelPolicy] = None,
-            ) -> Tuple[jax.Array, jax.Array]:
-    """x: (B, S, d) -> (out (B, S, d), aux_loss scalar).
+            layer: Optional[jax.Array] = None,
+            ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """x: (B, S, d) -> (out (B, S, d), aux_loss scalar, counts (2,)).
+
+    ``counts`` are int32 ``[rows, hit]``: (token, expert) rows routed to
+    the held experts, and how many held experts got at least one.
+
+    With ``layer``, ``p`` holds every layer's weights stacked and the
+    layer's own are used: the ``ragged`` path reads them in place (its
+    groups offset into the stack), the others slice them out.
 
     ``dropless=True`` sets capacity = T (no token ever dropped) — used
     for decode, where capacity-drop noise would corrupt generation.
@@ -65,32 +79,43 @@ def moe_ffn(p: Dict[str, jax.Array], x: jax.Array,
 
     ``policy`` selects the expert-GEMM implementation: a non-xla
     ``moe_gemm`` choice switches the *dropless* path from the dense
-    (T, E, C) dispatch einsums to a megablocks-style grouped GEMM over
-    the per-token top-k expert rows (``kernels.moe_gemm``). Capacity-
-    dropping dispatch keeps the einsum structure regardless of policy
-    (the grouped path has no notion of dropping).
+    (T, E, C) dispatch einsums to grouped GEMMs over the per-token top-k
+    expert rows: ``ragged`` sorts the rows of held experts by expert and
+    drops the others before any product (``_routed_held``); any other
+    impl takes every row unsorted (``_routed_grouped``, all experts
+    held). Capacity-dropping dispatch keeps the einsum structure
+    regardless of policy (the grouped paths have no notion of dropping).
     """
     m = cfg.moe
     B, S, d = x.shape
     pol = resolve_policy(policy)
-    if dropless and pol.impl_for("moe_gemm") != "xla":
-        out, aux = _routed_grouped(p, x.reshape(B * S, d), cfg, pol)
-        return _add_shared(p, x, out.reshape(B, S, d), cfg), aux
+    impl = pol.impl_for("moe_gemm")
+    if dropless and impl == "ragged":
+        out, aux, counts = _routed_held(p, x.reshape(B * S, d), cfg, pol,
+                                        layer)
+        if layer is not None:
+            p = {k: v[layer] for k, v in p.items() if k.startswith("shared")}
+        return _add_shared(p, x, out.reshape(B, S, d), cfg), aux, counts
+    if layer is not None:
+        p = jax.tree.map(lambda a: a[layer], p)
+    if dropless and impl != "xla":
+        out, aux, counts = _routed_grouped(p, x.reshape(B * S, d), cfg, pol)
+        return _add_shared(p, x, out.reshape(B, S, d), cfg), aux, counts
     if token_chunk and not dropless and S % token_chunk == 0 \
             and token_chunk < S:
         return _moe_ffn_grouped(p, x, cfg, token_chunk)
     T = B * S
     xt = x.reshape(T, d)
-    E, K = m.n_experts, m.experts_per_token
+    E, K = m.held, m.experts_per_token
     if dropless:
         cap = T
     else:
         cap = int(math.ceil(K * T / E * m.capacity_factor))
         cap = max(K, min(cap, T))
 
-    out, aux = _routed_core(p, xt, cfg, cap)
+    out, aux, counts = _routed_core(p, xt, cfg, cap)
     out = out.reshape(B, S, d)
-    return _add_shared(p, x, out, cfg), aux
+    return _add_shared(p, x, out, cfg), aux, counts
 
 
 def _route(p: Dict[str, jax.Array], xt: jax.Array, cfg: ModelConfig,
@@ -116,19 +141,73 @@ def _route(p: Dict[str, jax.Array], xt: jax.Array, cfg: ModelConfig,
     return gate_vals, idx, aux
 
 
+def _counts(sizes: jax.Array) -> jax.Array:
+    """int32 [rows, experts hit] from per-held-expert row counts."""
+    return jnp.stack([jnp.sum(sizes), jnp.count_nonzero(sizes)]
+                     ).astype(jnp.int32)
+
+
+def _routed_held(p: Dict[str, jax.Array], xt: jax.Array, cfg: ModelConfig,
+                 policy: KernelPolicy, layer: Optional[jax.Array] = None,
+                 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """Dropless expert compute over the held experts' rows only.
+
+    Routing (``moe_route`` scope): softmax over all experts in float32,
+    top k, renormalised; the (token, k) rows routed to held experts are
+    sorted by expert, the others go last and belong to no group, so the
+    three SwiGLU products (``moe_gemm``, grouped: work scales with the
+    routed rows) never compute them. The combine gathers each token's
+    rows back and sums them by gate weight. With ``layer`` the expert
+    weights are every layer's, stacked: they are read as one set of
+    groups (a free reshape) and this layer's rows keyed into its own,
+    so no layer's weights are copied out of the stack."""
+    m = cfg.moe
+    T, d = xt.shape
+    Eh, K = m.held, m.experts_per_token
+    ws = [p[n].astype(xt.dtype) for n in ("wg", "wi", "wo")]
+    first, groups = 0, Eh
+    if layer is not None:
+        p = dict(p, router=p["router"][layer])
+        first, groups = layer * Eh, ws[0].shape[0] * Eh
+        ws = [w.reshape((groups,) + w.shape[2:]) for w in ws]
+    with jax.named_scope("moe_route"):
+        gate_vals, idx, aux = _route(p, xt, cfg)
+        local = idx.reshape(T * K)
+        held = local < Eh
+        key = jnp.where(held, local + first, groups).astype(jnp.int32)
+        order = jnp.argsort(key, stable=True)                  # (T*K,)
+        key_sorted = key[order]
+        x_sorted = xt[order // K]
+        sizes = jnp.bincount(jnp.where(held, local, Eh), length=Eh)
+    kw = dict(n_experts=groups, sorted_rows=True)
+    g = dispatch("moe_gemm", policy, x_sorted, ws[0], key_sorted, **kw)
+    u = dispatch("moe_gemm", policy, x_sorted, ws[1], key_sorted, **kw)
+    y = dispatch("moe_gemm", policy, swiglu(g, u), ws[2], key_sorted, **kw)
+    with jax.named_scope("moe_route"):
+        y = y[jnp.argsort(order)].reshape(T, K, d)             # unsort
+        gates = jnp.where(held.reshape(T, K), gate_vals, 0.0)
+        out = jnp.sum(y * gates[..., None].astype(y.dtype), axis=1)
+    return constrain(out, ("tokens", "embed")), aux, _counts(sizes)
+
+
 def _routed_grouped(p: Dict[str, jax.Array], xt: jax.Array,
                     cfg: ModelConfig, policy: KernelPolicy,
-                    ) -> Tuple[jax.Array, jax.Array]:
+                    ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Dropless expert compute as grouped GEMMs over (token, k) rows.
 
     Each token is replicated K times (one row per chosen expert); the
     three expert matmuls (wg, wi, wo) run through the ``moe_gemm``
     dispatch op — the megablocks-style structure the Pallas grouped
     kernel implements — and the K partial outputs are gate-combined.
-    Mathematically identical to the dropless capacity einsum."""
+    Mathematically identical to the dropless capacity einsum. Every
+    expert has to be held here."""
     m = cfg.moe
     T, d = xt.shape
     E, K = m.n_experts, m.experts_per_token
+    if m.held != E:
+        raise ValueError(
+            f"{cfg.name}: holds {m.held} of {E} experts; the unsorted "
+            f"grouped path needs them all (use moe_gemm='ragged')")
 
     gate_vals, idx, aux = _route(p, xt, cfg)
     x_rep = jnp.repeat(xt, K, axis=0)                          # (T*K, d)
@@ -143,15 +222,20 @@ def _routed_grouped(p: Dict[str, jax.Array], xt: jax.Array,
     y = dispatch("moe_gemm", policy, h, wo, eor, n_experts=E)  # (T*K, d)
     y = y.reshape(T, K, d) * gate_vals[..., None].astype(y.dtype)
     out = jnp.sum(y, axis=1)
-    return constrain(out, ("tokens", "embed")), aux
+    sizes = jnp.bincount(eor, length=E)
+    return constrain(out, ("tokens", "embed")), aux, _counts(sizes)
 
 
 def _routed_core(p: Dict[str, jax.Array], xt: jax.Array, cfg: ModelConfig,
-                 cap: int) -> Tuple[jax.Array, jax.Array]:
-    """Capacity-based dispatch for one token group. xt: (T, d)."""
+                 cap: int) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """Capacity-based dispatch for one token group. xt: (T, d).
+
+    Experts are indexed among the held ones: a row routed to an expert
+    held elsewhere has an all-zero one-hot, so it is neither dispatched
+    nor combined."""
     m = cfg.moe
     T, d = xt.shape
-    E, K = m.n_experts, m.experts_per_token
+    E, K = m.held, m.experts_per_token
 
     gate_vals, idx, aux = _route(p, xt, cfg)
     one_hot_k = jax.nn.one_hot(idx, E, dtype=jnp.float32)       # (T, K, E)
@@ -191,7 +275,8 @@ def _routed_core(p: Dict[str, jax.Array], xt: jax.Array, cfg: ModelConfig,
     ye = constrain(ye, ("experts", "capacity", "embed"))
     out = jnp.einsum("tec,ecd->td", comb, ye)
     out = constrain(out, ("tokens", "embed"))
-    return out, aux
+    sizes = jnp.sum(one_hot_k, axis=(0, 1))
+    return out, aux, _counts(sizes)
 
 
 def _moe_ffn_grouped(p: Dict[str, jax.Array], x: jax.Array,
@@ -205,13 +290,15 @@ def _moe_ffn_grouped(p: Dict[str, jax.Array], x: jax.Array,
     """
     m = cfg.moe
     B, S, d = x.shape
-    E, K = m.n_experts, m.experts_per_token
+    E, K = m.held, m.experts_per_token
     cap = int(math.ceil(K * token_chunk / E * m.capacity_factor))
     cap = max(K, min(cap, token_chunk))
     xg = x.reshape(B * (S // token_chunk), token_chunk, d)
-    out, aux = jax.vmap(lambda xt: _routed_core(p, xt, cfg, cap))(xg)
+    out, aux, counts = jax.vmap(
+        lambda xt: _routed_core(p, xt, cfg, cap))(xg)
     out = out.reshape(B, S, d)
-    return _add_shared(p, x, out, cfg), jnp.mean(aux)
+    return (_add_shared(p, x, out, cfg), jnp.mean(aux),
+            jnp.sum(counts, axis=0).astype(jnp.int32))
 
 
 def _add_shared(p, x, out, cfg: ModelConfig) -> jax.Array:

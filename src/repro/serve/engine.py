@@ -35,8 +35,9 @@ on the host plane, on the clock the device planes share, so a device
 trace says which phase the host was in while the chip sat idle; with it
 off each costs one context-manager entry and builds no metadata.
 ``serve.wait`` (the step program still running) and ``serve.fetch``
-(the device-to-host copy of its logits) split what was one
-``np.asarray``.
+(the device-to-host copy of its output: one token id a slot under a
+greedy sampler, whose argmax ends the program, else the logits) split
+what was one ``np.asarray``.
 """
 from __future__ import annotations
 
@@ -97,6 +98,13 @@ class EngineStats:
     # the paged-vs-fixed utilization headline in serve_throughput.
     live_token_steps: int = 0
     alloc_token_steps: int = 0
+    # the window layers' ring group of a model of mixed kinds (paged)
+    ring_alloc_token_steps: int = 0
+    # expert models (paged engine): (token, expert) rows routed to held
+    # experts, and held experts with at least one row, summed over
+    # layers and decode steps
+    expert_rows: int = 0
+    experts_hit: int = 0
     # prefix caching (paged engine only)
     prefix_hits: int = 0         # admissions that reused >= 1 prefix page
     prefix_hit_tokens: int = 0   # prompt tokens served from shared pages
@@ -228,16 +236,25 @@ class ServeEngine:
         self._host_pos = np.zeros((n_slots,), np.int64)
 
         stats = self.stats
+        # a greedy engine's programs end in each row's argmax: a step
+        # copies one id a slot to the host, not a row of logits
+        pick = ((lambda lg: jnp.argmax(lg, axis=-1).astype(jnp.int32))
+                if self.sampler.on_device else (lambda lg: lg))
 
         def _step_fn(p, cache, tokens):
-            return self._decode(p, cache, tokens)
+            cache, logits = self._decode(p, cache, tokens)
+            return cache, pick(logits)
 
         def _prefill_fn(p, toks, lengths):
             stats.prefill_traces[(toks.shape[1], toks.shape[0])] += 1
-            return prefill(p, cfg, {"tokens": toks}, max_len, rt,
-                           lengths=lengths)
+            cache, logits = prefill(p, cfg, {"tokens": toks}, max_len, rt,
+                                    lengths=lengths)
+            return cache, pick(logits)
 
-        self._step = jax.jit(_step_fn)
+        # a model of mixed attention kinds rewrites the cache in place:
+        # its pools and weights fill the chip, no second pool fits
+        donate = (1,) if cfg.mixed_attention else ()
+        self._step = jax.jit(_step_fn, donate_argnums=donate)
         self._prefill = jax.jit(_prefill_fn)
 
     # -------------------------------------------------------- placement hooks
@@ -280,12 +297,24 @@ class ServeEngine:
         ``kv_dtype='int8'``."""
         return sum(int(self.cache[k].size
                        * jnp.dtype(self.cache[k].dtype).itemsize)
-                   for k in ("k", "v", "kp", "vp", "ks", "vs")
+                   for k in ("k", "v", "kw", "vw", "kp", "vp", "kpw", "vpw",
+                             "ks", "vs")
                    if k in self.cache)
 
     def _live_tokens(self, active: List[int]) -> int:
         W = self.scheduler.window
         return int(sum(min(int(self._host_pos[s]), W) for s in active))
+
+    def _count_kv(self, active: List[int]):
+        """Per decode step: the active slots' live and allocated cache
+        tokens."""
+        self.stats.live_token_steps += self._live_tokens(active)
+        self.stats.alloc_token_steps += self._allocated_tokens(active)
+
+    def _fetch(self, out) -> np.ndarray:
+        """The step's output on the host: token ids, or logits where
+        the host samples."""
+        return np.asarray(out)
 
     def _allocated_tokens(self, active: List[int]) -> int:
         """Cache tokens the active requests hold allocated. The fixed
@@ -355,7 +384,8 @@ class ServeEngine:
 
     def _prefill_group(self, group: List[Request], plan: AdmissionPlan):
         """Run the (bucketed) batched prefill for one admission group;
-        returns the single-call cache + per-row logits."""
+        returns the single-call cache + per-row output (a token id, or
+        logits where the host samples)."""
         width = max(self.scheduler.admit_width, len(group))
         P = plan.prefill_len
         # TraceMe metadata splits at commas: ids are joined by spaces
@@ -372,25 +402,25 @@ class ServeEngine:
                     toks[j] = req.prompt[:P]
                     lengths[j] = P
             with self._ctx():
-                single, logits = self._prefill(
+                single, out = self._prefill(
                     self.params, jnp.asarray(toks), jnp.asarray(lengths))
         self.stats.prefills += 1
         self.stats.prefill_tokens += width * P
         with TraceAnnotation("serve.prefill_fetch"):
-            return single, np.asarray(logits)
+            return single, np.asarray(out)
 
     def _admit_group(self, group: List[Request], plan: AdmissionPlan,
                      slots: List[int]):
-        single, logits_np = self._prefill_group(group, plan)
+        single, out = self._prefill_group(group, plan)
         with TraceAnnotation("serve.splice"):
             self.cache = _splice(self.cache, single, slots,
                                  rows=range(len(group)),
                                  axes=self._cache_axes())
         for j, (req, slot) in enumerate(zip(group, slots)):
-            self._finish_admit(req, slot, plan, logits_np[j])
+            self._finish_admit(req, slot, plan, out[j])
 
     def _finish_admit(self, req: Request, slot: int, plan: AdmissionPlan,
-                      logits_row: Optional[np.ndarray],
+                      row: Optional[np.ndarray],
                       start_pos: Optional[int] = None):
         """Per-slot bookkeeping shared by every admission path: seed the
         sampler stream, arm the chunked-prefill tail (or emit the first
@@ -403,20 +433,21 @@ class ServeEngine:
         self._host_pos[slot] = start_pos
         if start_pos < len(req.prompt):
             # chunked prefill: the rest of the prompt rides the
-            # decode step as forced inputs; prefill logits unused.
+            # decode step as forced inputs; prefill output unused.
             self.last_tokens[slot] = int(req.prompt[start_pos])
             self._tails[slot] = [int(t)
                                  for t in req.prompt[start_pos + 1:]]
         else:
             self._tails[slot] = []
-            self._emit(slot, logits_row)
+            self._emit(slot, row)
 
     # ---------------------------------------------------------------- step
-    def _emit(self, slot: int, logits_row: np.ndarray):
-        """Sample one token for ``slot``; retire the request on budget
+    def _emit(self, slot: int, row: np.ndarray):
+        """Take ``slot``'s token from its row of a program's output (the
+        id, or logits to sample); retire the request on budget
         exhaustion or a stop token."""
         req = self.slots[slot]
-        tok = self.sampler.sample(logits_row, self._rngs[slot])
+        tok = self.sampler.sample(row, self._rngs[slot])
         req.out_tokens.append(tok)
         self.last_tokens[slot] = tok
         self.stats.tokens_out += 1
@@ -443,18 +474,17 @@ class ServeEngine:
         active = [i for i, r in enumerate(self.slots) if r is not None]
         if not active:
             return 0
-        self.stats.live_token_steps += self._live_tokens(active)
-        self.stats.alloc_token_steps += self._allocated_tokens(active)
+        self._count_kv(active)
         self.stats.max_active = max(self.stats.max_active, len(active))
         meta = ({"slots": " ".join(map(str, active))}
                 if TraceAnnotation.is_enabled() else {})
         with TraceAnnotation("serve.decode", **meta), self._ctx():
-            self.cache, logits = self._step(
+            self.cache, out = self._step(
                 self.params, self.cache, jnp.asarray(self.last_tokens))
         with TraceAnnotation("serve.wait"):
-            jax.block_until_ready(logits)
+            jax.block_until_ready(out)
         with TraceAnnotation("serve.fetch"):
-            logits_np = np.asarray(logits)
+            out_np = self._fetch(out)
         with TraceAnnotation("serve.sample"):
             for slot in active:
                 self._host_pos[slot] += 1
@@ -463,7 +493,7 @@ class ServeEngine:
                     self.last_tokens[slot] = self._tails[slot].pop(0)
                     self.stats.forced_tokens += 1
                 else:
-                    self._emit(slot, logits_np[slot])
+                    self._emit(slot, out_np[slot])
         self.stats.steps += 1
         self.stats.occupancy_sum += len(active)
         return len(active)

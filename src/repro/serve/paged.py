@@ -21,6 +21,11 @@ concurrency. This module replaces that paradigm:
   the pages back to the pool. Prefill still runs at the scheduler's
   bucketed shapes, then a jitted scatter writes the rows through the
   page table, so the compile-count bound is unchanged.
+* two cache groups — a model of mixed attention kinds keeps its full
+  layers' pages as above and its window layers' rows in a second pool,
+  where slot ``s`` owns the ring of pages ``s * npw + 1 .. (s + 1) *
+  npw`` (``npw = ceil(W/ps)``) under a page table written once: a ring
+  never outgrows its window, so that group needs no allocator.
 * prefix caching — full prompt pages are registered under a chained
   content hash; a later prompt sharing the prefix maps those physical
   pages into its table (refcount++), sets ``pos`` past them, and
@@ -248,7 +253,7 @@ class PagedServeEngine(ServeEngine):
             raise ValueError(f"page_size must be >= 1, got {page_size}")
         self.page_size = int(page_size)
         window = max_len
-        if cfg.sliding_window:
+        if cfg.sliding_window and not cfg.mixed_attention:
             window = min(cfg.sliding_window, max_len)
         self._npp = page_count(window, page_size)   # page-table width
         if page_budget is None:
@@ -268,6 +273,10 @@ class PagedServeEngine(ServeEngine):
             page_budget = base + 1
         self.n_pages = int(page_budget)
         self.pages = PagedKVCache(self.n_pages, self.page_size)
+        # the window layers' group of a model of mixed kinds: pages per
+        # slot's ring (``paged_cache_spec``'s ring pool), 0 without one
+        self._npp_ring = page_count(min(cfg.sliding_window, max_len),
+                                    page_size) if cfg.mixed_attention else 0
         self._prefix_on = bool(prefix_cache) \
             and cfg.family in PAD_SAFE_FAMILIES \
             and not cfg.sliding_window
@@ -276,6 +285,9 @@ class PagedServeEngine(ServeEngine):
             [([], []) for _ in range(n_slots)]
         super().__init__(params, cfg, rt, n_slots=n_slots,
                          max_len=max_len, **kw)
+        if self._npp_ring:
+            self.cache["ptw"] = self.cache["ptw"].at[:].set(
+                jnp.asarray(self._ring_table()))
 
         ps = self.page_size
 
@@ -387,7 +399,7 @@ class PagedServeEngine(ServeEngine):
         if not self._has_kv:
             # pure-SSM: state cache, nothing pages — splice exactly the
             # leaves prefill produced (the page table rides untouched)
-            single, logits_np = self._prefill_group(group, plan)
+            single, out = self._prefill_group(group, plan)
             with TraceAnnotation("serve.splice"):
                 names = [n for n in self.cache if n in single]
                 sub = _splice({n: self.cache[n] for n in names},
@@ -395,7 +407,7 @@ class PagedServeEngine(ServeEngine):
                               rows=range(len(group)), axes=PAGED_CACHE_AXES)
                 self.cache = dict(self.cache, **sub)
             for j, (req, slot) in enumerate(zip(group, slots)):
-                self._finish_admit(req, slot, plan, logits_np[j])
+                self._finish_admit(req, slot, plan, out[j])
             return
         cold: List[Tuple[Request, int]] = []
         for req, slot in zip(group, slots):
@@ -438,7 +450,7 @@ class PagedServeEngine(ServeEngine):
         P = plan.prefill_len
         group = [req for req, _ in pairs]
         slots = [slot for _, slot in pairs]
-        single, logits_np = self._prefill_group(group, plan)
+        single, out = self._prefill_group(group, plan)
 
         with TraceAnnotation("serve.scatter"):
             n_scatter = page_count(min(P, W), ps)
@@ -465,6 +477,8 @@ class PagedServeEngine(ServeEngine):
                         self.cache["kp"], self.cache["vp"],
                         single["k"], single["v"], jnp.asarray(page_ids))
                     self.cache = dict(self.cache, kp=kp, vp=vp)
+            if self._npp_ring:
+                self._scatter_ring(slots, single, width)
 
         rows = []
         for j, (req, slot) in enumerate(pairs):
@@ -490,7 +504,25 @@ class PagedServeEngine(ServeEngine):
             self.cache = dict(self.cache, **sub)
             self._set_page_table(slots, rows)
         for j, (req, slot) in enumerate(pairs):
-            self._finish_admit(req, slot, plan, logits_np[j])
+            self._finish_admit(req, slot, plan, out[j])
+
+    def _ring_table(self) -> np.ndarray:
+        """The window layers' page table: slot ``s``'s ring is pages
+        ``s * npw + 1 .. (s + 1) * npw`` (page 0 is the null page)."""
+        npw = self._npp_ring
+        return 1 + np.arange(self.n_slots * npw, dtype=np.int32).reshape(
+            self.n_slots, npw)
+
+    def _scatter_ring(self, slots: List[int], single, width: int):
+        """Scatter the window layers' prefill rings into the admitted
+        slots' own rings (the group's pad rows into the null page)."""
+        page_ids = np.zeros((width, self._npp_ring), np.int32)
+        page_ids[:len(slots)] = self._ring_table()[slots]
+        with self._ctx():
+            kp, vp = self._scatter(self.cache["kpw"], self.cache["vpw"],
+                                   single["kw"], single["vw"],
+                                   jnp.asarray(page_ids))
+        self.cache = dict(self.cache, kpw=kp, vpw=vp)
 
     def _set_page_table(self, slots: List[int], rows):
         """Write ``rows`` (ragged lists of physical pages) into the
@@ -518,6 +550,22 @@ class PagedServeEngine(ServeEngine):
         held = sum(len(sh) + len(pv)
                    for sh, pv in (self._slot_pages[s] for s in active))
         return held * self.page_size
+
+    def _count_kv(self, active: List[int]):
+        super()._count_kv(active)
+        self.stats.ring_alloc_token_steps += \
+            len(active) * self._npp_ring * self.page_size
+
+    def _fetch(self, out) -> np.ndarray:
+        """The step's output, and an expert model's step counters in
+        the same ``device_get``."""
+        counts = self.cache.get("expert_counts")
+        if counts is None:
+            return super()._fetch(out)
+        out_np, counts = jax.device_get((out, counts))
+        self.stats.expert_rows += int(counts[0])
+        self.stats.experts_hit += int(counts[1])
+        return np.asarray(out_np)
 
     @property
     def prefix_hit_rate(self) -> float:

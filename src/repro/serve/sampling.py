@@ -23,13 +23,22 @@ class Sampler:
     sampling with an optional top-k filter.
 
     ``top_k=0`` means the full vocabulary; ``seed`` roots every
-    per-request stream (see :meth:`stream`).
+    per-request stream (see :meth:`stream`). A greedy engine takes the
+    argmax on the device and copies one id per slot to the host;
+    ``host=True`` copies the logits instead and takes it here (for a
+    caller that reads them).
     """
 
     kind: str = "greedy"
     temperature: float = 1.0
     top_k: int = 0
     seed: int = 0
+    host: bool = False
+
+    @property
+    def on_device(self) -> bool:
+        """Whether the engine's programs return token ids, not logits."""
+        return self.kind == "greedy" and not self.host
 
     def __post_init__(self):
         if self.kind not in SAMPLER_KINDS:
@@ -53,7 +62,10 @@ class Sampler:
 
     def sample(self, logits: np.ndarray,
                rng: Optional[np.random.Generator] = None) -> int:
-        """One token id from a (V,) logit row."""
+        """One token id from a (V,) logit row; where :attr:`on_device`,
+        the row is the engine program's argmax, already the id."""
+        if self.on_device:
+            return int(logits)
         logits = np.asarray(logits, np.float64).reshape(-1)
         if self.kind == "greedy":
             return int(np.argmax(logits))

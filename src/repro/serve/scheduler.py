@@ -20,7 +20,9 @@ programs (times the number of admission widths in use), ever:
 
 Pad mode is additionally capped at the KV window ``W`` for
 sliding-window models: a padded length beyond ``W`` would rotate pad
-keys over live rows in the circular cache.
+keys over live rows in the circular cache. A model of mixed attention
+kinds is not capped: its window layers' ring is filled from each row's
+real length (``models.model._fill_kv_ring``).
 """
 from __future__ import annotations
 
@@ -90,8 +92,9 @@ class Scheduler:
     # ------------------------------------------------------------------
     @property
     def window(self) -> int:
-        """KV window W (the pad cap for sliding-window models)."""
-        if self.cfg.sliding_window:
+        """KV window W (the pad cap for sliding-window models; the full
+        layers' rows in a model of mixed kinds)."""
+        if self.cfg.sliding_window and not self.cfg.mixed_attention:
             return min(self.cfg.sliding_window, self.max_len)
         return self.max_len
 
@@ -109,7 +112,8 @@ class Scheduler:
         # chunk mode (and its length-1 floor for prompts below the
         # smallest bucket) is only reachable for recurrent families or
         # window-capped padding
-        chunk_reachable = not self.pad_safe or bool(self.cfg.sliding_window)
+        chunk_reachable = not self.pad_safe or (
+            bool(self.cfg.sliding_window) and not self.cfg.mixed_attention)
         if chunk_reachable and min(self._buckets) > 1:
             lens.add(1)
         return tuple(sorted(lens))
@@ -137,7 +141,8 @@ class Scheduler:
         a sliding-window cache wraps by design, so a request's live
         pages never exceed ``ceil(W / page_size)`` no matter how long
         the prompt — long prompts the window can serve must be admitted,
-        not rejected.
+        not rejected. (A model of mixed kinds counts its full layers'
+        pages here; each slot owns a whole ring for its window layers.)
         """
         if page_size < 1:
             raise ValueError(f"page_size must be >= 1, got {page_size}")

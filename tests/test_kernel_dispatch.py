@@ -57,7 +57,8 @@ def test_explicit_policy_overrides_flag():
 
 
 @pytest.mark.parametrize("backend,mesh,given,want", [
-    ("tpu", None, None, KernelPolicy(paged_decode_attention="pallas")),
+    ("tpu", None, None, KernelPolicy(paged_decode_attention="pallas",
+                                     moe_gemm="ragged")),
     ("cpu", None, None, XLA_POLICY),
     ("tpu", "mesh", None, XLA_POLICY),
     ("tpu", None, XLA_POLICY, XLA_POLICY),
@@ -66,7 +67,8 @@ def test_explicit_policy_overrides_flag():
 def test_serving_runtime_default_policy(monkeypatch, backend, mesh, given,
                                         want):
     """On a TPU with the pool on one device the launcher serves paged
-    decode attention with the Pallas kernel and every other op with XLA;
+    decode attention with the Pallas kernel, expert layers with grouped
+    GEMMs over their rows (``ragged``) and every other op with XLA;
     the CPU and a mesh stay all-XLA; a policy given wins."""
     from repro.launch.serve import serving_runtime
     monkeypatch.setattr(jax, "default_backend", lambda: backend)

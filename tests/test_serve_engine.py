@@ -221,6 +221,33 @@ def test_greedy_sampler_matches_argmax(params):
     assert a == b                      # greedy ignores the seed
 
 
+@pytest.mark.parametrize("page_size", [0, 8], ids=["fixed", "paged"])
+def test_greedy_argmax_on_device_matches_host(params, page_size):
+    """A greedy engine's programs end in the argmax and a step copies
+    one int32 id a slot; the tokens equal the host's argmax of the
+    copied logits (``Sampler(host=True)``), token for token."""
+    from repro.launch.serve import build_engine
+
+    def run(sampler):
+        eng = build_engine(params, CFG, RT, n_slots=2, max_len=64,
+                           sampler=sampler, page_size=page_size)
+        seen = []
+        fetch = eng._fetch
+        eng._fetch = lambda out: seen.append(fetch(out)) or seen[-1]
+        for i in range(3):
+            eng.submit(Request(rid=i,
+                               prompt=(np.arange(5 + 3 * i) * 7
+                                       % CFG.vocab_size).astype(np.int32),
+                               max_new_tokens=6))
+        return {r.rid: r.out_tokens for r in eng.run()}, seen
+
+    dev, dev_out = run(Sampler())
+    host, host_out = run(Sampler(host=True))
+    assert dev == host
+    assert all(o.shape == (2,) and o.dtype == np.int32 for o in dev_out)
+    assert all(o.shape == (2, CFG.vocab_size) for o in host_out)
+
+
 # ---------------------------------------------------------- termination
 def test_eos_termination(params):
     base = ServeEngine(params, CFG, RT, n_slots=1, max_len=64)

@@ -58,6 +58,13 @@ def _shapes(sharding, *specs):
             for s, d in specs]
 
 
+@jax.jit
+def _ragged(x, w, expert_of_row):
+    from repro.kernels.dispatch import implementations
+    return implementations("moe_gemm")["ragged"](
+        x, w, expert_of_row, n_experts=w.shape[0], sorted_rows=True)
+
+
 def _kernel_case(name):
     """(jitted op, operand specs, static kwargs) at real widths."""
     bf, i8, f32, i32 = "bfloat16", "int8", "float32", "int32"
@@ -113,6 +120,28 @@ def _kernel_case(name):
             ops.moe_grouped_matmul,
             [((512, 2048), bf), ((60, 2048, 1408), bf), ((512,), i32)],
             {"n_experts": 60}),
+        # the mellum2-12b-a2.5b.agentcode cell: 32 slots, 32 query and 4
+        # kv heads of 128; the window layers' ring of 64 pages a slot
+        # (the kernel reads min(context, 1024) rows), the full layers'
+        # table of 288
+        "paged_decode_attention-mellum2-ring": (
+            ops.paged_decode_attention,
+            [((32, 32, 128), bf), ((2049, PAGE, 4, 128), bf),
+             ((2049, PAGE, 4, 128), bf), ((32, 64), i32),
+             ((32, 64 * PAGE), "bool")], {}),
+        "paged_decode_attention-mellum2-full": (
+            ops.paged_decode_attention,
+            [((32, 32, 128), bf), ((9217, PAGE, 4, 128), bf),
+             ((9217, PAGE, 4, 128), bf), ((32, 288), i32),
+             ((32, 288 * PAGE), "bool")], {}),
+        # its 16 held experts of 896 over d_model 2304, rows sorted by
+        # expert: a decode step's 32 x 8 rows and a 3584 prefill's
+        "moe_gemm-ragged-decode": (
+            _ragged, [((256, 2304), bf), ((16, 2304, 896), bf),
+                      ((256,), i32)], {}),
+        "moe_gemm-ragged-prefill": (
+            _ragged, [((28672, 2304), bf), ((16, 2304, 896), bf),
+                      ((28672,), i32)], {}),
     }
     return cases[name]
 
@@ -121,7 +150,10 @@ def _kernel_case(name):
     "paged_decode_attention", "paged_decode_attention-starcoder2-3b",
     "paged_decode_attention-minicpm-2b", "quant_paged_decode_attention",
     "decode_attention", "quant_decode_attention", "flash_attention",
-    "rmsnorm", "quant_matmul", "ssd_scan", "moe_gemm"])
+    "rmsnorm", "quant_matmul", "ssd_scan", "moe_gemm",
+    "paged_decode_attention-mellum2-ring",
+    "paged_decode_attention-mellum2-full", "moe_gemm-ragged-decode",
+    "moe_gemm-ragged-prefill"])
 def test_kernel_lowers_to_mosaic_for_v5e(one_chip, name):
     fn, specs, kw = _kernel_case(name)
     compiled = fn.lower(*_shapes(one_chip, *specs), **kw).compile()
@@ -168,7 +200,7 @@ def test_paged_decode_step_fits_one_v5e_by_default(one_chip):
         mp.setattr(jax, "default_backend", lambda: "tpu")
         rt = serve.serving_runtime("bfloat16")
     assert rt.kernel_policy() == KernelPolicy(
-        paged_decode_attention="pallas")
+        paged_decode_attention="pallas", moe_gemm="ragged")
     cfg = get_arch("minicpm-2b")
     slots, max_len, n_pages = 2, 4096, 385
     spec = paged_cache_spec(cfg, slots, n_pages, PAGE, max_len, "bfloat16")
@@ -185,4 +217,40 @@ def test_paged_decode_step_fits_one_v5e_by_default(one_chip):
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     assert total < HBM_BYTES, f"{total / 2**30:.2f} GiB"
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_mixed_kinds_decode_step_fits_one_v5e_by_default(one_chip):
+    """The launcher's TPU default compiles one bf16 decode step of
+    mellum2-12b-a2.5b, 16 of its 64 experts held, over the
+    ``.agentcode`` cell's two cache groups (32 slots, 4608 tokens: 9,217
+    pages for the full layers, 2,049 for the window layers' rings), its
+    cache donated as the engine donates it, within the chip's HBM."""
+    import dataclasses
+    from repro.launch import serve
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(jax, "default_backend", lambda: "tpu")
+        rt = serve.serving_runtime("bfloat16")
+    cfg = get_arch("mellum2-12b-a2.5b")
+    cfg = cfg.replace(moe=dataclasses.replace(cfg.moe, n_held=16))
+    slots, max_len = 32, 4608
+    spec = paged_cache_spec(cfg, slots, slots * 288 + 1, PAGE, max_len,
+                            "bfloat16")
+    assert spec["kpw"][0] == (21, 2049, PAGE, 4, 128)
+    assert spec["kp"][0] == (7, 9217, PAGE, 4, 128)
+    cache = {k: jax.ShapeDtypeStruct(s, jnp.dtype(d), sharding=one_chip)
+             for k, (s, d) in spec.items()}
+    params = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        abstract_params(cfg, "bfloat16"))
+    tokens = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=one_chip)
+    step = jax.jit(lambda p, c, t: decode_step_paged(
+        p, cfg, c, t, rt, page_size=PAGE, window=max_len),
+        donate_argnums=(1,))
+    compiled = step.lower(params, cache, tokens).compile()
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert total < 14 * 2**30, f"{total / 2**30:.2f} GiB"
+    assert mem.alias_size_in_bytes > 3 * 2**30      # the pools, in place
     assert "tpu_custom_call" in compiled.as_text()

@@ -21,7 +21,7 @@ PUBLISHED_B = {
     "mixtral-8x22b": 141.0, "qwen2-moe-a2.7b": 14.3, "chatglm3-6b": 6.2,
     "stablelm-12b": 12.1, "minicpm-2b": 2.7, "starcoder2-3b": 3.0,
     "qwen2-vl-7b": 7.6, "hubert-xlarge": 0.96, "zamba2-2.7b": 2.7,
-    "mamba2-1.3b": 1.3,
+    "mamba2-1.3b": 1.3, "mellum2-12b-a2.5b": 12.0,
 }
 
 
