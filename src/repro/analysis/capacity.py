@@ -176,13 +176,14 @@ def _abstract_paged_cache_tree(cfg, n_slots: int, page_budget: int,
     import jax
     import jax.numpy as jnp
 
-    from repro.models.model import PAGED_CACHE_AXES, paged_cache_spec
+    from repro.models.model import paged_cache_axes, paged_cache_spec
 
     cs = paged_cache_spec(cfg, n_slots, page_budget, page_size, max_len,
                           kv_dtype=kv_dtype)
     ab = {k: jax.ShapeDtypeStruct(s, jnp.dtype(d))
           for k, (s, d) in cs.items()}
-    return ab, {k: PAGED_CACHE_AXES[k] for k in cs}, cs
+    axes = paged_cache_axes(cfg, kv_dtype)
+    return ab, {k: axes[k] for k in cs}, cs
 
 
 # ===========================================================================

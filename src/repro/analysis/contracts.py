@@ -157,7 +157,8 @@ def run_pass(ctx: AnalysisContext) -> List[Finding]:
     from repro.kernels.tune import TUNE_PRESETS
     from repro.models.model import (CACHE_AXES, PAGED_CACHE_AXES,
                                     cache_spec, page_count,
-                                    paged_cache_spec, _cache_window)
+                                    paged_cache_axes, paged_cache_spec,
+                                    _cache_window)
 
     findings: List[Finding] = []
     max_len, ps = ctx.preset.max_len, ctx.preset.page_size
@@ -170,7 +171,7 @@ def run_pass(ctx: AnalysisContext) -> List[Finding]:
         n_pages = 2 * page_count(W, ps) + 1
         findings += check_cache_axes(
             paged_cache_spec(cfg, 2, n_pages, ps, max_len),
-            PAGED_CACHE_AXES, axes_name="PAGED_CACHE_AXES",
+            paged_cache_axes(cfg), axes_name="PAGED_CACHE_AXES",
             symbol=f"paged_cache_spec/{arch}")
 
     findings += check_axis_resolvable(CACHE_AXES, RECIPES,

@@ -1,7 +1,7 @@
 """Paged split-KV decode attention — TPU Pallas.
 
 The paged serving engine (``repro.serve.paged``) keeps each layer's KV
-cache as one pooled ``(n_pages, page_size, Hkv, D)`` buffer plus a
+cache as one pooled ``(n_pages, page_size) + page_row`` buffer plus a
 per-sequence page table; a decode step must read a sequence's pages
 *through the table* while reducing them into one attention output.
 
@@ -24,10 +24,12 @@ and the online softmax are float32; probabilities enter P·V unrounded
   score it in one MXU tile ``(Hq, rows * Hkv)`` whose entries across
   heads are masked off, the row mask along lanes (slicing one head's
   rows out of a page costs more than the Hkv-fold MXU work);
-* one query per kv head (``G == 1``): a page is read as one
-  ``(ps, Hkv * D)`` slab — narrow heads (D 64) would leave a page's
-  ``(Hkv, D)`` tiles unaligned — and scored against a block-diagonal
-  query, ``(rows, Hkv)``, the row mask along sublanes.
+* one query per kv head (``G == 1``): the pool stores a row as one
+  ``Hkv * D`` vector (:func:`page_row`), so a page is one ``(ps, Hkv *
+  D)`` slab — narrow heads (D 64) would leave a page's ``(Hkv, D)``
+  tiles unaligned, and reshaping such a pool costs a relayout of all of
+  it per call — scored against a block-diagonal query, ``(rows, Hkv)``,
+  the row mask along sublanes.
 
 The int8 kernel (``repro.kernels.quant``) keeps the older geometry
 shared below (:func:`paged_grid_spec`): grid ``(B, n_splits,
@@ -40,7 +42,7 @@ from __future__ import annotations
 
 import functools
 import math
-from typing import Optional
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -50,6 +52,18 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels.decode_attention import merge_partials
 
 NEG_INF = -1e30
+
+
+def page_row(n_heads: int, n_kv_heads: int, head_dim: int
+             ) -> Tuple[int, ...]:
+    """The shape of one cached row in a float page pool, from the head
+    counts: one ``Hkv * D`` vector where each kv head has one query
+    (``G == 1``), the slab the live-page kernel reads a page as; else
+    ``(Hkv, D)``, the grouped kernel's block. The pool's spec, its
+    writers and both decode attentions read this one choice."""
+    if n_heads == n_kv_heads:
+        return (n_kv_heads * head_dim,)
+    return (n_kv_heads, head_dim)
 
 
 def page_mask_words(kv_mask, n_pages: int, page_size: int) -> jax.Array:
@@ -374,16 +388,22 @@ def paged_decode_attention_splitkv(q, k_pages, v_pages, page_table,
                                    kv_mask, *,
                                    pages_per_block: Optional[int] = None,
                                    interpret: bool = True) -> jax.Array:
-    """q: (B, Hq, D); k/v_pages: (P, ps, Hkv, D) pooled page buffers;
-    page_table: (B, NP) int32 physical page of each logical page;
-    kv_mask: (B, NP * ps) bool over logical rows. ``pages_per_block``
-    overrides :func:`block_pages`."""
+    """q: (B, Hq, D); k/v_pages: (P, ps) + :func:`page_row` pooled
+    page buffers, read in place; page_table: (B, NP) int32 physical page
+    of each logical page; kv_mask: (B, NP * ps) bool over logical rows.
+    ``pages_per_block`` overrides :func:`block_pages`."""
     B, Hq, D = q.shape
-    P, ps, Hkv = k_pages.shape[:3]
+    ps = k_pages.shape[1]
+    Hkv = Hq if k_pages.ndim == 3 else k_pages.shape[2]
+    if k_pages.shape[2:] != page_row(Hq, Hkv, D):
+        raise ValueError(f"pool rows {k_pages.shape[2:]} are not the rows "
+                         f"{page_row(Hq, Hkv, D)} of {Hq} query and {Hkv} "
+                         f"kv heads of {D}")
     G = Hq // Hkv
     NP = page_table.shape[1]
+    page = k_pages.shape[1:]
     pb = pages_per_block or block_pages(
-        ps * Hkv * D * k_pages.dtype.itemsize, NP)
+        math.prod(page) * k_pages.dtype.itemsize, NP)
     pb = max(1, min(pb, NP))
     NPp = -(-NP // pb) * pb
     # pad the table with the reserved null page; its rows are masked
@@ -394,16 +414,12 @@ def paged_decode_attention_splitkv(q, k_pages, v_pages, page_table,
     if grouped:
         qk = q
         parts = [(B, Hq, D), (B, Hq, 1), (B, Hq, 1)]
-        page = (ps, Hkv, D)
     else:
         # block-diagonal query: column h holds head h's q on its D rows
         eye = jnp.eye(Hkv, dtype=q.dtype)
         qk = (q[:, :, :, None] * eye[None, :, None, :]) \
             .reshape(B, Hkv * D, Hkv)
         parts = [(B, 1, Hkv * D), (B, 1, Hkv), (B, 1, Hkv)]
-        page = (ps, Hkv * D)
-        k_pages = k_pages.reshape(P, ps, Hkv * D)
-        v_pages = v_pages.reshape(P, ps, Hkv * D)
 
     def block(shape):
         return pl.BlockSpec((1,) + shape[1:],

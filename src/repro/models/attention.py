@@ -133,15 +133,16 @@ def paged_decode_attention(q, k_pages, v_pages, page_table,
                            kv_mask) -> jax.Array:
     """One-token decode through a paged KV pool (XLA gather reference).
 
-    q: (B, Hq, D); k/v_pages: (P, ps, Hkv, D) pooled page buffers;
-    page_table: (B, NP) int32 maps each sequence's logical page to a
-    physical pool page; kv_mask: (B, NP * ps) bool over logical rows.
-    Rows pointing at unowned pages MUST be masked off by the caller —
-    the gather itself reads whatever the table says.
+    q: (B, Hq, D); k/v_pages: (P, ps, Hkv, D) pooled page buffers, or
+    (P, ps, Hkv * D) with rows merged (``kernels.paged_attention.
+    page_row``); page_table: (B, NP) int32 maps each sequence's logical
+    page to a physical pool page; kv_mask: (B, NP * ps) bool over
+    logical rows. Rows pointing at unowned pages MUST be masked off by
+    the caller — the gather itself reads whatever the table says.
     """
-    B = q.shape[0]
-    ps, Hkv, D = k_pages.shape[1:]
+    B, _, D = q.shape
+    ps = k_pages.shape[1]
     NP = page_table.shape[1]
-    k = k_pages[page_table].reshape(B, NP * ps, Hkv, D)
-    v = v_pages[page_table].reshape(B, NP * ps, Hkv, D)
+    k = k_pages[page_table].reshape(B, NP * ps, -1, D)
+    v = v_pages[page_table].reshape(B, NP * ps, -1, D)
     return decode_attention(q, k, v, kv_mask)

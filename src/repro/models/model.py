@@ -26,6 +26,7 @@ import jax.numpy as jnp
 from repro.configs.base import ModelConfig
 from repro.dist.sharding import constrain
 from repro.kernels.dispatch import KernelPolicy, dispatch
+from repro.kernels.paged_attention import page_row
 from repro.models import layers as L
 from repro.models import moe as MOE
 from repro.models import ssm as SSM
@@ -298,8 +299,9 @@ def _scan_blocks(params, cfg: ModelConfig, x, positions, rt: ModelRuntime):
 
 def _periods(cfg: ModelConfig, tree, n: Optional[int] = None):
     """Layer-stacked leaves ``(n_layers, ...)`` -> ``(n_periods, n, ...)``
-    (``n`` layers of each period; default the whole period)."""
-    P = len(cfg.layer_pattern)
+    (``n`` layers of each period; default the whole period, of one
+    layer in a model of one kind)."""
+    P = len(cfg.layer_pattern) if cfg.mixed_attention else 1
     if cfg.n_layers % P:
         raise ValueError(f"{cfg.name}: {cfg.n_layers} layers are not whole "
                          f"periods of {cfg.layer_pattern}")
@@ -660,8 +662,11 @@ def paged_cache_spec(cfg: ModelConfig, n_slots: int, n_pages: int,
     bf16, one scale per cached row per kv head.
 
     KV lives in one pooled buffer per layer group — ``kp``/``vp``:
-    ``(L, n_pages, page_size, Hkv, hd)`` — addressed through per-slot
-    page tables ``pt: (n_slots, ceil(W / page_size))``. Physical page 0
+    ``(L, n_pages, page_size) + row`` — addressed through per-slot page
+    tables ``pt: (n_slots, ceil(W / page_size))``. A float pool's
+    ``row`` is the decode kernel's (``page_row``: ``(Hkv * hd,)`` with
+    one query per kv head, else ``(Hkv, hd)``); an int8 pool's is
+    ``(Hkv, hd)``. Physical page 0
     is reserved as the null page: unowned table entries point at it and
     retired slots write their (masked) decode rows into it, so stale
     slots can never corrupt pages that have been rebound to live
@@ -676,10 +681,10 @@ def paged_cache_spec(cfg: ModelConfig, n_slots: int, n_pages: int,
     model adds ``expert_counts``: int32 ``[rows, experts hit]`` of the
     last decode step, summed over layers.
     """
-    hd = cfg.head_dim
     W = _cache_window(cfg, max_len)
     npp = page_count(W, page_size)
     kvd = kv_dtype or dtype
+    row = (page_size,) + _pool_row(cfg, kvd)
     spec: Dict[str, Tuple[Tuple, Any]] = {
         "pos": ((n_slots,), jnp.int32),
         "pt": ((n_slots, npp), jnp.int32),
@@ -692,7 +697,6 @@ def paged_cache_spec(cfg: ModelConfig, n_slots: int, n_pages: int,
             raise NotImplementedError("int8 KV for mixed attention kinds")
         npw = page_count(_ring_window(cfg, max_len), page_size)
         n_ring_pages = n_slots * npw + 1
-        row = (page_size, cfg.n_kv_heads, hd)
         nf, nw = len(layers_of(cfg, "full")), len(layers_of(cfg, "window"))
         spec["ptw"] = ((n_slots, npw), jnp.int32)
         spec["kp"] = ((nf, n_pages) + row, kvd)
@@ -700,7 +704,7 @@ def paged_cache_spec(cfg: ModelConfig, n_slots: int, n_pages: int,
         spec["kpw"] = ((nw, n_ring_pages) + row, kvd)
         spec["vpw"] = ((nw, n_ring_pages) + row, kvd)
     elif fam in ("dense", "moe", "vlm", "audio"):
-        shape = (cfg.n_layers, n_pages, page_size, cfg.n_kv_heads, hd)
+        shape = (cfg.n_layers, n_pages) + row
         spec["kp"] = (shape, kvd)
         spec["vp"] = (shape, kvd)
         if kvd == "int8":
@@ -712,7 +716,7 @@ def paged_cache_spec(cfg: ModelConfig, n_slots: int, n_pages: int,
         spec["ssm"] = ((cfg.n_layers,) + cs["ssm"], "float32")
     if fam == "hybrid":
         n_groups = cfg.n_layers // cfg.shared_attn_period
-        shape = (n_groups, n_pages, page_size, cfg.n_kv_heads, hd)
+        shape = (n_groups, n_pages) + row
         spec["kp"] = (shape, kvd)
         spec["vp"] = (shape, kvd)
         if kvd == "int8":
@@ -721,10 +725,19 @@ def paged_cache_spec(cfg: ModelConfig, n_slots: int, n_pages: int,
     return spec
 
 
+def _pool_row(cfg: ModelConfig, kv_dtype: str) -> Tuple[int, ...]:
+    """A KV pool's row: the decode kernel's (``page_row``) for a float
+    pool; ``(Hkv, hd)`` for an int8 one, beside its per-head scales."""
+    if kv_dtype == "int8":
+        return (cfg.n_kv_heads, cfg.head_dim)
+    return page_row(cfg.n_heads, cfg.n_kv_heads, cfg.head_dim)
+
+
 #: Logical axis names for the paged cache. The page pool has no batch
 #: axis (slots share it through their tables) — it shards along
 #: ``kv_heads``, the same name the contiguous cache uses, so the
-#: existing decode recipes place it tensor-parallel unchanged.
+#: existing decode recipes place it tensor-parallel unchanged. A pool of
+#: merged rows has the axes of :func:`paged_cache_axes`.
 PAGED_CACHE_AXES = {
     "pos": ("batch",),
     "pt": ("batch", None),
@@ -739,6 +752,18 @@ PAGED_CACHE_AXES = {
     "conv": CACHE_AXES["conv"],
     "ssm": CACHE_AXES["ssm"],
 }
+
+
+def paged_cache_axes(cfg: ModelConfig, kv_dtype: Optional[str] = None
+                     ) -> Dict[str, tuple]:
+    """:data:`PAGED_CACHE_AXES` as ``cfg``'s paged cache lays its leaves
+    out: a float pool of merged rows, ``(L, n_pages, page_size, Hkv *
+    hd)``, shards its row axis (kv heads, head-major) as ``kv_heads``."""
+    if len(_pool_row(cfg, kv_dtype)) == 2:
+        return PAGED_CACHE_AXES
+    merged = (None, None, None, "kv_heads")
+    return dict(PAGED_CACHE_AXES,
+                **{k: merged for k in ("kp", "vp", "kpw", "vpw")})
 
 
 def init_paged_cache(cfg: ModelConfig, n_slots: int, n_pages: int,
@@ -756,7 +781,8 @@ def write_prefill_pages(kp, vp, k, v, page_ids, *, page_size: int):
 
     k/v: (L, width, S, Hkv, hd) — the ``prefill`` cache's contiguous
     rows (circular layout for windowed configs, which the page mapping
-    preserves: logical row r lives at page ``r // page_size``).
+    preserves: logical row r lives at page ``r // page_size``), laid
+    into the pool's own row shape.
     page_ids: (width, n_write) int32 — the physical destination of each
     row's first ``n_write`` logical pages; pad rows point at the null
     page (their garbage stays masked forever).
@@ -770,16 +796,16 @@ def _scatter_rows_to_pages(pool, rows, page_ids, page_size: int):
     """Scatter (L, width, S, ...) contiguous rows into an
     (L, n_pages, page_size, ...) pool at ``page_ids`` — shared by the
     KV payload buffers and the int8 scale side-bands (which simply lack
-    the trailing head_dim axis)."""
+    the trailing head_dim axis). Each row takes the pool's row shape (a
+    merged pool's ``Hkv * hd``)."""
     L, width, S = rows.shape[:3]
     n_write = page_ids.shape[1]
     need = n_write * page_size
     if need > S:
         pad = ((0, 0), (0, 0), (0, need - S)) + ((0, 0),) * (rows.ndim - 3)
         rows = jnp.pad(rows, pad)
-    tail = rows.shape[3:]
     blocks = rows[:, :, :need].reshape(
-        (L, width * n_write, page_size) + tail)
+        (L, width * n_write, page_size) + pool.shape[3:])
     flat = page_ids.reshape(-1)
     return pool.at[:, flat].set(blocks.astype(pool.dtype))
 
@@ -838,13 +864,13 @@ def _attn_decode_one_paged(p, x, kp, vp, pt, pos, window: int,
     q, k = L.apply_rope(q, k, posv, cfg, kind)
     row = (pos % W).astype(jnp.int32)                    # (B,)
     phys = jnp.take_along_axis(pt, (row // ps)[:, None], axis=1)[:, 0]
+    at = (phys, row % ps) if layer is None else (layer, phys, row % ps)
+    # the new rows in the pool's row shape (a merged row is free)
+    new = lambda a, pool: a[:, 0].reshape(                # noqa: E731
+        (B,) + pool.shape[len(at):]).astype(pool.dtype)
     with jax.named_scope("kv_write"):
-        if layer is None:
-            kp = kp.at[phys, row % ps].set(k[:, 0].astype(kp.dtype))
-            vp = vp.at[phys, row % ps].set(v[:, 0].astype(vp.dtype))
-        else:
-            kp = kp.at[layer, phys, row % ps].set(k[:, 0].astype(kp.dtype))
-            vp = vp.at[layer, phys, row % ps].set(v[:, 0].astype(vp.dtype))
+        kp = kp.at[at].set(new(k, kp))
+        vp = vp.at[at].set(new(v, vp))
     Wp = pt.shape[1] * ps
     ar = jnp.arange(Wp)[None, :]
     mask = (ar <= pos[:, None]) & (ar < W)               # (B, Wp)
@@ -908,52 +934,38 @@ def decode_step_paged(params, cfg: ModelConfig, cache: Dict[str, jax.Array],
                       tokens: jax.Array, rt: ModelRuntime,
                       *, page_size: int, window: int,
                       ) -> Tuple[Dict[str, jax.Array], jax.Array]:
-    """Paged twin of :func:`decode_step`: same per-family bodies, with
-    attention layers routed through the page pool. Pure-SSM configs have
-    no KV to page — their state cache decodes unchanged (the page table
-    rides along untouched)."""
+    """Paged twin of :func:`decode_step`, with attention layers routed
+    through the page pool. Float KV of every attention family decodes in
+    one body, :func:`_decode_step_paged_float`, its pools read and
+    written in place; int8 KV and the hybrid scan each layer's pools as
+    slices. Pure-SSM configs have no KV to page — their state cache
+    decodes unchanged (the page table rides along untouched)."""
     fam = cfg.family
     if fam == "ssm":
         return decode_step(params, cfg, cache, tokens, rt)
-    if cfg.mixed_attention:
-        return _decode_step_paged_mixed(params, cfg, cache, tokens, rt,
+    quant = "ks" in cache
+    if fam in ("dense", "moe", "vlm", "audio") and not quant:
+        return _decode_step_paged_float(params, cfg, cache, tokens, rt,
                                         page_size=page_size, window=window)
     pos = cache["pos"]
     pt = cache["pt"]
     x = params["embed"].astype(rt.dtype)[tokens]          # (B, d)
     pol = rt.kernel_policy()
-    quant = "ks" in cache
 
-    if fam in ("dense", "moe", "vlm", "audio"):
-        if quant:
-            def body(x_, xs):
-                lp, kp, vp, ks, vs = xs
-                x2, kp, vp, ks, vs = _attn_decode_one_paged_q(
-                    lp, x_, kp, vp, ks, vs, pt, pos, window, page_size,
-                    cfg, rt)
-                return x2, (kp, vp, ks, vs)
+    if fam in ("dense", "moe", "vlm", "audio"):             # int8 KV
+        def body(x_, xs):
+            lp, kp, vp, ks, vs = xs
+            x2, kp, vp, ks, vs = _attn_decode_one_paged_q(
+                lp, x_, kp, vp, ks, vs, pt, pos, window, page_size,
+                cfg, rt)
+            return x2, (kp, vp, ks, vs)
 
-            x, (kp_new, vp_new, ks_new, vs_new) = jax.lax.scan(
-                body, x, (params["blocks"], cache["kp"], cache["vp"],
-                          cache["ks"], cache["vs"]),
-                unroll=rt.unroll_layers)
-            new_cache = dict(cache, pos=pos + 1, kp=kp_new, vp=vp_new,
-                             ks=ks_new, vs=vs_new)
-        else:
-            def body(carry, xs):
-                x_, n_ = carry
-                lp, kp, vp = xs
-                x2, kp, vp, n2 = _attn_decode_one_paged(
-                    lp, x_, kp, vp, pt, pos, window, page_size, cfg, rt)
-                return (x2, _add_counts(n_, n2)), (kp, vp)
-
-            (x, counts), (kp_new, vp_new) = jax.lax.scan(
-                body, (x, _zero_counts(cfg)),
-                (params["blocks"], cache["kp"], cache["vp"]),
-                unroll=rt.unroll_layers)
-            new_cache = dict(cache, pos=pos + 1, kp=kp_new, vp=vp_new)
-            if counts is not None:
-                new_cache["expert_counts"] = counts
+        x, (kp_new, vp_new, ks_new, vs_new) = jax.lax.scan(
+            body, x, (params["blocks"], cache["kp"], cache["vp"],
+                      cache["ks"], cache["vs"]),
+            unroll=rt.unroll_layers)
+        new_cache = dict(cache, pos=pos + 1, kp=kp_new, vp=vp_new,
+                         ks=ks_new, vs=vs_new)
     else:  # hybrid
         period = cfg.shared_attn_period
         n_groups = cfg.n_layers // period
@@ -1027,53 +1039,54 @@ def _zero_counts(cfg: ModelConfig) -> Optional[jax.Array]:
     return jnp.zeros((2,), jnp.int32) if cfg.moe is not None else None
 
 
-def _decode_step_paged_mixed(params, cfg: ModelConfig, cache, tokens, rt,
+def _decode_step_paged_float(params, cfg: ModelConfig, cache, tokens, rt,
                              *, page_size: int, window: int):
-    """:func:`decode_step_paged` for a model of mixed attention kinds:
-    a scan over periods of ``layer_pattern``, the layers of a period
-    unrolled. Window layers write and read their ring (``kpw``/``vpw``
-    under ``ptw``, ``_ring_window`` rows), full layers the full-length
-    group (``kp``/``vp`` under ``pt``, ``window`` rows). The pools ride
-    the scan's carry and take each new row in place; expert weights stay
-    stacked (their grouped GEMMs read each layer's in place)."""
+    """:func:`decode_step_paged` for float KV: a scan over periods of
+    ``layer_pattern`` (a model of one kind is a period of one layer), the
+    layers of a period unrolled. Window layers of a model of mixed kinds
+    write and read their ring (``kpw``/``vpw`` under ``ptw``,
+    ``_ring_window`` rows); every other layer the main group (``kp``/
+    ``vp`` under ``pt``, ``window`` rows: a one-kind sliding window's
+    already). The pools ride the scan's carry, take each new row in
+    place and are read through the page table offset to the layer's
+    pages: no layer's pool is copied. Expert weights stay stacked (their
+    grouped GEMMs read each layer's in place)."""
     pos = cache["pos"]
-    ring = _ring_window(cfg, window)
     x = params["embed"].astype(rt.dtype)[tokens]          # (B, d)
-    pattern = cfg.layer_pattern
-    nw = pattern.count("window")
-    nf = len(pattern) - nw
+    pattern = cfg.layer_pattern if cfg.mixed_attention else (None,)
+    # per kind: its pools, page table and rows
+    groups = {kind: ("kpw", "vpw", "ptw", _ring_window(cfg, window))
+              if kind == "window" else ("kp", "vp", "pt", window)
+              for kind in pattern}
+    pools = {n: cache[n] for n in ("kp", "vp", "kpw", "vpw") if n in cache}
     blocks = dict(params["blocks"])
     experts = blocks.pop("moe", None)
 
     def body(carry, xs):
-        x_, n_, kw, vw, kf, vf = carry
+        x_, n_, pools = carry
         lp, period = xs
-        iw = jf = 0
+        seen = {kind: 0 for kind in groups}
         for j, kind in enumerate(pattern):
             pj = jax.tree.map(lambda a: a[j], lp)
             moe_layer = None
             if experts is not None:
                 pj["moe"], moe_layer = experts, period * len(pattern) + j
-            if kind == "window":
-                x_, kw, vw, n2 = _attn_decode_one_paged(
-                    pj, x_, kw, vw, cache["ptw"], pos, ring, page_size, cfg,
-                    rt, kind, layer=period * nw + iw, moe_layer=moe_layer)
-                iw += 1
-            else:
-                x_, kf, vf, n2 = _attn_decode_one_paged(
-                    pj, x_, kf, vf, cache["pt"], pos, window, page_size, cfg,
-                    rt, kind, layer=period * nf + jf, moe_layer=moe_layer)
-                jf += 1
+            kn, vn, ptn, rows = groups[kind]
+            layer = period * pattern.count(kind) + seen[kind]
+            seen[kind] += 1
+            x_, k2, v2, n2 = _attn_decode_one_paged(
+                pj, x_, pools[kn], pools[vn], cache[ptn], pos, rows,
+                page_size, cfg, rt, kind, layer=layer, moe_layer=moe_layer)
+            pools = dict(pools, **{kn: k2, vn: v2})
             n_ = _add_counts(n_, n2)
-        return (x_, n_, kw, vw, kf, vf), None
+        return (x_, n_, pools), None
 
     n_periods = cfg.n_layers // len(pattern)
-    (x, counts, kw, vw, kf, vf), _ = jax.lax.scan(
-        body, (x, _zero_counts(cfg), cache["kpw"], cache["vpw"], cache["kp"],
-               cache["vp"]),
+    (x, counts, pools), _ = jax.lax.scan(
+        body, (x, _zero_counts(cfg), pools),
         (_periods(cfg, blocks), jnp.arange(n_periods)),
         unroll=rt.unroll_layers)
-    new_cache = dict(cache, pos=pos + 1, kpw=kw, vpw=vw, kp=kf, vp=vf)
+    new_cache = dict(cache, pos=pos + 1, **pools)
     if counts is not None:
         new_cache["expert_counts"] = counts
     pol = rt.kernel_policy()

@@ -251,10 +251,10 @@ class ServeEngine:
                                     lengths=lengths)
             return cache, pick(logits)
 
-        # a model of mixed attention kinds rewrites the cache in place:
-        # its pools and weights fill the chip, no second pool fits
-        donate = (1,) if cfg.mixed_attention else ()
-        self._step = jax.jit(_step_fn, donate_argnums=donate)
+        # the step rewrites the cache in place: no second cache is live
+        # while it runs (a paged pool and the weights fill the chip);
+        # ``self.cache`` is the step's returned cache from then on
+        self._step = jax.jit(_step_fn, donate_argnums=(1,))
         self._prefill = jax.jit(_prefill_fn)
 
     # -------------------------------------------------------- placement hooks

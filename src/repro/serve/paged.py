@@ -12,7 +12,7 @@ concurrency. This module replaces that paradigm:
   past the shared prefix, which land in the writer's private pages, so
   a shared page is never mutated).
 * :class:`PagedServeEngine` — the ServeEngine with the contiguous cache
-  swapped for one pooled ``(L, n_pages, page_size, Hkv, hd)`` KV buffer
+  swapped for one pooled ``(L, n_pages, page_size) + row`` KV buffer
   per layer group plus per-slot page tables
   (``models.model.paged_cache_spec``). Admission allocates a request's
   worst-case pages up front (``Scheduler.pages_for`` — window-capped,
@@ -55,7 +55,7 @@ from jax.profiler import TraceAnnotation
 
 from repro.models.model import (PAGED_CACHE_AXES, decode_step_paged,
                                 init_paged_cache, page_count,
-                                write_prefill_pages,
+                                paged_cache_axes, write_prefill_pages,
                                 write_prefill_pages_quant)
 from repro.serve.engine import Request, ServeEngine, _splice
 from repro.serve.scheduler import PAD_SAFE_FAMILIES, AdmissionPlan
@@ -317,7 +317,7 @@ class PagedServeEngine(ServeEngine):
                                  window=self.scheduler.window)
 
     def _cache_axes(self) -> Dict[str, tuple]:
-        return PAGED_CACHE_AXES
+        return paged_cache_axes(self.cfg, self.rt.kv_dtype)
 
     @property
     def _has_kv(self) -> bool:

@@ -115,9 +115,14 @@ PAGED_CASES = {
 
 @pytest.mark.parametrize("case", sorted(PAGED_CASES))
 def test_paged_decode_attention_matches_xla(case):
-    """The live-page kernel against the XLA gather, in float32, through
-    a shuffled page table; an empty slot's output is only finite."""
-    from repro.kernels.paged_attention import paged_decode_attention_splitkv
+    """The live-page kernel against the XLA gather and the dense
+    reference over the gathered rows, in float32, through a shuffled
+    page table, on a pool stored in the rows the kernel reads (one
+    ``Hkv * D`` vector a row for MHA); the XLA gather reads the same
+    pages as ``(Hkv, D)`` rows alike. An empty slot's output is only
+    finite."""
+    from repro.kernels.paged_attention import (page_row,
+                                               paged_decode_attention_splitkv)
     from repro.models.attention import paged_decode_attention
 
     Hq, Hkv, D, ps, NP, pb, (kind, *how) = PAGED_CASES[case]
@@ -132,16 +137,36 @@ def test_paged_decode_attention_matches_xla(case):
     P = B * NP + 1
     ks = jax.random.split(KEY, 3)
     q = jax.random.normal(ks[0], (B, Hq, D), jnp.float32)
-    kp = jax.random.normal(ks[1], (P, ps, Hkv, D), jnp.float32)
-    vp = jax.random.normal(ks[2], (P, ps, Hkv, D), jnp.float32)
+    k4 = jax.random.normal(ks[1], (P, ps, Hkv, D), jnp.float32)
+    v4 = jax.random.normal(ks[2], (P, ps, Hkv, D), jnp.float32)
+    row = page_row(Hq, Hkv, D)
+    assert (len(row) == 1) == (Hq == Hkv)
+    kp, vp = (a.reshape((P, ps) + row) for a in (k4, v4))
     pages = np.random.default_rng(0).permutation(np.arange(1, P))
     pt = jnp.asarray(pages.reshape(B, NP), jnp.int32)
     out = np.asarray(paged_decode_attention_splitkv(
         q, kp, vp, pt, mask, pages_per_block=pb))
     want = np.asarray(paged_decode_attention(q, kp, vp, pt, mask))
+    rows = k4[pt].reshape(B, NP * ps, Hkv, D), v4[pt].reshape(
+        B, NP * ps, Hkv, D)
+    dense = np.asarray(ref.decode_attention_ref(q, *rows, mask))
     live = mask.any(axis=1)
     np.testing.assert_allclose(out[live], want[live], atol=1e-5, rtol=1e-5)
+    np.testing.assert_allclose(out[live], dense[live], atol=1e-5, rtol=1e-5)
+    np.testing.assert_array_equal(
+        want, np.asarray(paged_decode_attention(q, k4, v4, pt, mask)))
     assert np.isfinite(out).all()
+
+
+def test_paged_decode_attention_refuses_rows_it_does_not_read():
+    """A pool of one query per kv head stored as ``(Hkv, D)`` rows would
+    cost a relayout of the whole pool per call: the kernel refuses it."""
+    from repro.kernels.paged_attention import paged_decode_attention_splitkv
+    q = jnp.zeros((1, 4, 64), jnp.float32)
+    kp = jnp.zeros((3, 8, 4, 64), jnp.float32)
+    with pytest.raises(ValueError, match="not the rows"):
+        paged_decode_attention_splitkv(q, kp, kp, jnp.ones((1, 2), jnp.int32),
+                                       np.ones((1, 16), bool))
 
 
 @pytest.mark.parametrize("mask,want", [

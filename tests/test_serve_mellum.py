@@ -199,13 +199,15 @@ def test_cache_groups_reserve_and_release():
 @pytest.mark.parametrize("arch", ["starcoder2-3b", "minicpm-2b"])
 def test_models_of_one_kind_keep_their_cache(arch):
     """One attention kind and no experts: the paged cache is the one
-    group it always was, with no counters."""
+    group it always was, with no counters; its rows are the decode
+    kernel's (one merged vector a row for minicpm-2b's 36 query and kv
+    heads of 64, kv heads apart for starcoder2-3b's groups)."""
     cfg = ARCHS[arch]
     spec = paged_cache_spec(cfg, 16, 4097, 16, 4096)
     assert set(spec) == {"pos", "pt", "kp", "vp"}
     assert spec["pt"][0] == (16, 256)
-    assert spec["kp"][0] == (cfg.n_layers, 4097, 16, cfg.n_kv_heads,
-                             cfg.head_dim)
+    row = {"starcoder2-3b": (2, 128), "minicpm-2b": (36 * 64,)}[arch]
+    assert spec["kp"][0] == (cfg.n_layers, 4097, 16) + row
 
 
 # ------------------------------------------------------------------- rope

@@ -317,3 +317,91 @@ def test_prefix_cache_off_for_sliding_window():
     eng = PagedServeEngine(p, cfg, RT, n_slots=1, max_len=64,
                            page_size=8, prefix_cache=True)
     assert not eng._prefix_on
+
+
+# ----------------------------------------------- float pools read in place
+#: one query per kv head at minicpm-2b's head width: the pool stores
+#: merged rows, ``Hkv * hd``, the slab the live-page kernel reads
+MHA = CFG.replace(n_kv_heads=CFG.n_heads, d_head=64)
+ONE_KIND = {"gqa": smoke_config(ARCHS["starcoder2-3b"]),
+            "mha": MHA,
+            "window": smoke_config(ARCHS["mixtral-8x22b"])}
+
+
+def _scans(jaxpr):
+    """Every scan equation of ``jaxpr`` and of the jaxprs inside it."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "scan":
+            yield eqn
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    yield from _scans(inner)
+
+
+@pytest.mark.parametrize("name", sorted(ONE_KIND))
+def test_paged_decode_pools_ride_the_scan_carry(name):
+    """A one-kind float decode step carries its pools through the layer
+    scan: no scan output (ys) is the pool or a layer's slice of it, and
+    an MHA pool's rows are merged."""
+    from repro.models import abstract_params, decode_step_paged
+    from repro.models.model import paged_cache_spec
+
+    cfg = ONE_KIND[name]
+    window = min(cfg.sliding_window or 64, 64)
+    spec = paged_cache_spec(cfg, 2, 2 * page_count(window, 8) + 1, 8, 64,
+                            "float32")
+    pool = spec["kp"][0]
+    rows = (cfg.n_kv_heads * cfg.head_dim,) if name == "mha" \
+        else (cfg.n_kv_heads, cfg.head_dim)
+    assert pool[3:] == rows
+    cache = {k: jax.ShapeDtypeStruct(s, np.dtype(d))
+             for k, (s, d) in spec.items()}
+    jaxpr = jax.make_jaxpr(lambda p, c, t: decode_step_paged(
+        p, cfg, c, t, RT, page_size=8, window=window))(
+        abstract_params(cfg, "float32"), cache,
+        jax.ShapeDtypeStruct((2,), np.int32)).jaxpr
+    scans = list(_scans(jaxpr))
+    assert scans
+    carried = []
+    for eqn in scans:
+        nc = eqn.params["num_carry"]
+        ys = [tuple(v.aval.shape) for v in eqn.outvars[nc:]]
+        assert pool not in ys and pool[1:] not in ys, (name, ys)
+        carried += [tuple(v.aval.shape) for v in eqn.outvars[:nc]]
+    assert carried.count(pool) == 2                 # kp and vp
+
+
+def test_paged_step_donates_the_cache(params):
+    """The engine's step rewrites the cache in place: the cache a step
+    was handed is deleted, the one it returned is live."""
+    eng = PagedServeEngine(params, CFG, RT, n_slots=2, max_len=64,
+                           page_size=8)
+    eng.submit(Request(rid=0, prompt=np.arange(5, dtype=np.int32),
+                       max_new_tokens=4))
+    eng.step()                                  # admits, then decodes
+    before = eng.cache
+    eng.step()
+    assert before["kp"].is_deleted() and before["vp"].is_deleted()
+    assert not eng.cache["kp"].is_deleted()
+
+
+@pytest.mark.parametrize("kernels", [False, True])
+def test_merged_rows_token_parity_with_prefix_hits(kernels):
+    """MHA pools of merged rows: prefill rows scattered into them and
+    read back by decode (XLA gather, or the live-page kernel in
+    interpret mode) serve the contiguous engine's tokens, with prefix
+    cache hits and without."""
+    p = init_params(jax.random.PRNGKey(0), MHA)
+    rt = ModelRuntime(dtype="float32", remat="none", attn_chunk=16,
+                      moe_dropless=True, use_kernels=kernels)
+    prompts = _prefix_prompts(MHA) + _prompts(MHA, n=2)
+    want = _serve(ServeEngine(p, MHA, RT, n_slots=2, max_len=64), prompts)
+    warm = PagedServeEngine(p, MHA, rt, n_slots=2, max_len=64, page_size=8)
+    assert warm.cache["kp"].shape[3:] == (MHA.n_kv_heads * 64,)
+    assert _serve(warm, prompts) == want
+    assert warm.stats.prefix_hits > 0
+    cold = PagedServeEngine(p, MHA, rt, n_slots=2, max_len=64, page_size=8,
+                            prefix_cache=False)
+    assert _serve(cold, prompts) == want

@@ -9,6 +9,9 @@ may load the TPU library.
 """
 from __future__ import annotations
 
+import math
+import re
+
 import pytest
 
 import jax
@@ -72,8 +75,10 @@ def _kernel_case(name):
     B, H, D, W = SLOTS, 36, 64, MAX_LEN
     NP = page_count(W, PAGE)
     P = SLOTS * NP + 1
-    paged = [((B, H, D), bf), ((P, PAGE, H, D), bf), ((P, PAGE, H, D), bf),
-             ((B, NP), i32), ((B, NP * PAGE), "bool")]
+    # one query per kv head: the pool's rows are merged, H * D
+    paged = [((B, H, D), bf), ((P, PAGE, H * D), bf),
+             ((P, PAGE, H * D), bf), ((B, NP), i32),
+             ((B, NP * PAGE), "bool")]
     cases = {
         "paged_decode_attention": (ops.paged_decode_attention, paged, {}),
         # the benchmark cells' pools: starcoder2-3b.code (GQA, 16 slots)
@@ -85,8 +90,8 @@ def _kernel_case(name):
              ((16, 256 * PAGE), "bool")], {}),
         "paged_decode_attention-minicpm-2b": (
             ops.paged_decode_attention,
-            [((2, H, D), bf), ((385, PAGE, H, D), bf),
-             ((385, PAGE, H, D), bf), ((2, 256), i32),
+            [((2, H, D), bf), ((385, PAGE, H * D), bf),
+             ((385, PAGE, H * D), bf), ((2, 256), i32),
              ((2, 256 * PAGE), "bool")], {}),
         "quant_paged_decode_attention": (
             ops.quant_paged_decode_attention,
@@ -190,19 +195,41 @@ def test_paged_decode_step_fits_one_v5e(one_chip, policy):
     assert ("tpu_custom_call" in compiled.as_text()) == (policy == "pallas")
 
 
-def test_paged_decode_step_fits_one_v5e_by_default(one_chip):
-    """The launcher's TPU default (the live-page kernel for paged decode
-    attention, XLA elsewhere) compiles one bf16 decode step of
-    minicpm-2b over the ``.longdoc`` cell's pool — 2 slots, 385 pages
-    of 16, 4096 tokens — within the chip's HBM."""
+#: what a loop's plumbing and the kernel's view of the stacked pool do
+#: with a pool-sized value: move no bytes
+PLUMBING = ("parameter", "get-tuple-element", "tuple", "while", "bitcast")
+
+
+def _pool_copies(hlo: str, pool) -> list:
+    """Instructions of a compiled program that copy, slice or update a
+    whole layer's pool (``pool[1:]``, operand or result) or copy the
+    whole stack ``pool``: anything but the in-place row writes, the
+    kernel's view of the stack and the loop's plumbing."""
+    layer = r"\[(1,)?" + ",".join(map(str, pool[1:])) + r"\]"
+    stack = "[" + ",".join(map(str, pool)) + "]"
+    bad = []
+    for line in hlo.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%(\S+) = (\S+) ([\w-]+)\(", line)
+        if m is None or m.group(3) in PLUMBING:
+            continue
+        name, shape, op = m.groups()
+        whole = stack in shape and (op == "copy"
+                                    or "dynamic-update-slice" in name)
+        if whole or re.search(layer, line):
+            bad.append(name)
+    return bad
+
+
+def _donated_step_hlo(one_chip, cfg, slots, n_pages, max_len):
+    """The launcher's TPU default decode step of ``cfg`` over a pool of
+    ``n_pages`` pages of 16, its cache donated as the engine donates it,
+    compiled: (memory analysis, HLO text, the pool's shape)."""
     from repro.launch import serve
     with pytest.MonkeyPatch.context() as mp:
         mp.setattr(jax, "default_backend", lambda: "tpu")
         rt = serve.serving_runtime("bfloat16")
     assert rt.kernel_policy() == KernelPolicy(
         paged_decode_attention="pallas", moe_gemm="ragged")
-    cfg = get_arch("minicpm-2b")
-    slots, max_len, n_pages = 2, 4096, 385
     spec = paged_cache_spec(cfg, slots, n_pages, PAGE, max_len, "bfloat16")
     cache = {k: jax.ShapeDtypeStruct(s, jnp.dtype(d), sharding=one_chip)
              for k, (s, d) in spec.items()}
@@ -211,13 +238,39 @@ def test_paged_decode_step_fits_one_v5e_by_default(one_chip):
         abstract_params(cfg, "bfloat16"))
     tokens = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=one_chip)
     step = jax.jit(lambda p, c, t: decode_step_paged(
-        p, cfg, c, t, rt, page_size=PAGE, window=max_len))
+        p, cfg, c, t, rt, page_size=PAGE, window=max_len),
+        donate_argnums=(1,))
     compiled = step.lower(params, cache, tokens).compile()
-    mem = compiled.memory_analysis()
+    return compiled.memory_analysis(), compiled.as_text(), spec["kp"][0]
+
+
+def test_paged_decode_step_fits_one_v5e_by_default(one_chip):
+    """The launcher's TPU default (the live-page kernel for paged decode
+    attention, XLA elsewhere) compiles one bf16 decode step of
+    minicpm-2b over the ``.longdoc`` cell's pool — 2 slots, 385 pages
+    of 16, 4096 tokens — within the chip's HBM, reading and writing its
+    pools of merged rows in place: no layer's pool is copied."""
+    mem, hlo, pool = _donated_step_hlo(one_chip, get_arch("minicpm-2b"),
+                                       2, 385, 4096)
+    assert pool == (40, 385, PAGE, 36 * 64)
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     assert total < HBM_BYTES, f"{total / 2**30:.2f} GiB"
-    assert "tpu_custom_call" in compiled.as_text()
+    # both pools, bf16, in place
+    assert mem.alias_size_in_bytes >= 2 * 2 * math.prod(pool)
+    assert "tpu_custom_call" in hlo
+    assert _pool_copies(hlo, pool) == []
+
+
+def test_grouped_decode_step_reads_its_pool_in_place(one_chip):
+    """starcoder2-3b over the ``.codegen`` cell's pool (16 slots, 4,097
+    pages, grouped heads: rows of kv heads apart) compiles with no copy,
+    slice or update of a layer's pool."""
+    mem, hlo, pool = _donated_step_hlo(one_chip, get_arch("starcoder2-3b"),
+                                       16, 4097, 4096)
+    assert pool == (30, 4097, PAGE, 2, 128)
+    assert mem.alias_size_in_bytes >= 2 * 2 * math.prod(pool)
+    assert _pool_copies(hlo, pool) == []
 
 
 def test_mixed_kinds_decode_step_fits_one_v5e_by_default(one_chip):
@@ -253,4 +306,7 @@ def test_mixed_kinds_decode_step_fits_one_v5e_by_default(one_chip):
              + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     assert total < 14 * 2**30, f"{total / 2**30:.2f} GiB"
     assert mem.alias_size_in_bytes > 3 * 2**30      # the pools, in place
-    assert "tpu_custom_call" in compiled.as_text()
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" in hlo
+    assert _pool_copies(hlo, spec["kp"][0]) == []
+    assert _pool_copies(hlo, spec["kpw"][0]) == []
